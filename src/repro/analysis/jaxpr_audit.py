@@ -9,7 +9,7 @@ HLO buffer bound — so the whole registry audits in seconds:
   anywhere in the trace (recursively through pjit/scan/while/cond
   sub-jaxprs). Scatter in load propagation, host callbacks, etc.
 * ``forbid_f64`` — no equation may *produce* a float64 value. Checked on
-  a trace taken under ``jax.experimental.enable_x64`` so latent leaks
+  a trace taken under ``jax.enable_x64(True)`` so latent leaks
   (code relying on x64-off canonicalization) are caught, not masked.
 * ``max_transient_elements`` — no equation output exceeds this element
   count: the bound that proves a blocked path streams slabs instead of
@@ -188,7 +188,7 @@ def audit_contract(c: Contract) -> list[Finding]:
         x64_trace = c.trace_x64 or c.trace
         try:
             import jax
-            with jax.experimental.enable_x64():
+            with jax.enable_x64(True):
                 closed64 = x64_trace()
         except Exception as e:
             add("audit-trace-error", f"x64 tracing failed: {e!r}")

@@ -142,10 +142,12 @@ class PendingGenomeEval:
     evaluation: the device computes while the host keeps working (archive
     updates, checkpoint writes — see ``opt.runner.AsyncStepper``).
     ``result()`` blocks on the device, builds the host-side reports, and is
-    idempotent."""
+    idempotent. ``arrays`` holds the dispatched device outputs, sharded
+    over the population axis (bucket-padded rows included)."""
 
-    def __init__(self, finisher):
+    def __init__(self, finisher, arrays: tuple = ()):
         self._finisher = finisher
+        self.arrays = arrays
         self._result: GenomeEvalResult | None = None
 
     def result(self) -> GenomeEvalResult:
@@ -421,7 +423,7 @@ def _adjacency_eval_fn(mesh, n: int, k_phys: int, euclid: bool,
     impl = functools.partial(_adjacency_eval, n=n, k_phys=k_phys,
                              euclid=euclid, max_hops=max_hops)
     f = shard_map(impl, mesh=mesh, in_specs=(P("data"),) + (P(),) * 17,
-                  out_specs=(P("data"),) * 3, check_rep=False)
+                  out_specs=(P("data"),) * 3, check_vma=False)
     return jax.jit(f, donate_argnums=(0,) if donate else ())
 
 
@@ -548,7 +550,7 @@ def _adjacency_faults_fn(mesh, n: int, k_phys: int, euclid: bool,
                              euclid=euclid, max_hops=max_hops)
     f = shard_map(impl, mesh=mesh,
                   in_specs=(P("data"), P(), P()) + (P(),) * 17,
-                  out_specs=(P("data"),) * 4, check_rep=False)
+                  out_specs=(P("data"),) * 4, check_vma=False)
     return jax.jit(f, donate_argnums=(0,) if donate else ())
 
 
@@ -714,7 +716,7 @@ class AdjacencyPipeline:
                                         throughput=np.asarray(thr)[:Pn],
                                         reports=reports)
 
-        return PendingGenomeEval(finish)
+        return PendingGenomeEval(finish, (lat, thr, len_sum))
 
     def evaluate(self, genomes: np.ndarray) -> GenomeEvalResult:
         """One fused jitted call for a whole (repaired) population."""
@@ -781,7 +783,7 @@ class AdjacencyPipeline:
                     reachable_fraction=np.asarray(reach)[:Pn],
                     reports=reports)
 
-        return PendingGenomeEval(finish)
+        return PendingGenomeEval(finish, (lat, thr, reach, len_sum))
 
     def evaluate_faults(self, genomes: np.ndarray, link_fail: np.ndarray,
                         node_fail: np.ndarray) -> FaultGridResult:
@@ -832,7 +834,7 @@ def _parametric_eval_fn(mesh, n_steps: int, max_hops: int):
     impl = functools.partial(_parametric_eval, n_steps=n_steps,
                              max_hops=max_hops)
     f = shard_map(impl, mesh=mesh, in_specs=(P("data"),) * 5,
-                  out_specs=(P("data"),) * 2, check_rep=False)
+                  out_specs=(P("data"),) * 2, check_vma=False)
     return jax.jit(f)
 
 

@@ -9,8 +9,10 @@ written. This module concentrates the counter-measures:
   kernel dispatch on the next-cheaper rung
   (``pallas_tiled -> xla_blocked -> xla``), warns once per (op, from, to)
   edge, and counts ``ops.fallback`` in the metrics registry.
-  ``REPRO_STRICT_BACKEND=1`` disables the ladder (a dispatch failure
-  raises); ``REPRO_CHAOS_BACKEND_FAIL=<backends>`` makes the listed
+  The ladder is off on a TPU, where a kernel that fails to compile or
+  dispatch is a fault to see, not to hide, and under
+  ``REPRO_STRICT_BACKEND=1`` (a dispatch failure raises);
+  ``REPRO_CHAOS_BACKEND_FAIL=<backends>`` makes the listed
   backends fail on purpose, which is how CI proves the ladder keeps
   tier-1 green.
 * **Non-finite quarantine** — ``quarantine_nonfinite`` swaps NaN/inf
@@ -26,8 +28,9 @@ written. This module concentrates the counter-measures:
   SIGTERM/SIGINT into a flag the optimizer loop polls (flush a final
   checkpoint, then exit); a second signal raises ``KeyboardInterrupt``.
 
-Everything here is stdlib + ``repro.obs`` + ``repro.utils.env`` only, so
-``kernels.ops`` can import it without cycles.
+Everything here is stdlib + ``repro.obs`` + ``repro.utils.env`` (and jax,
+to see the platform) only, so ``kernels.ops`` can import it without
+cycles.
 """
 from __future__ import annotations
 
@@ -38,6 +41,7 @@ import threading
 import time
 from contextlib import contextmanager
 
+import jax
 import numpy as np
 
 from ..obs import metrics as _metrics
@@ -85,7 +89,10 @@ def maybe_chaos_fail(backend: str) -> None:
 
 
 def strict_backend() -> bool:
-    return _env.get_bool("REPRO_STRICT_BACKEND")
+    """Whether a failed dispatch raises: always on a TPU, elsewhere under
+    ``REPRO_STRICT_BACKEND=1``."""
+    return (jax.default_backend() == "tpu"
+            or _env.get_bool("REPRO_STRICT_BACKEND"))
 
 
 _warned_edges: set[tuple[str, str, str]] = set()
@@ -101,8 +108,9 @@ def run_with_fallback(op: str, backend: str, attempt):
 
     ``attempt`` must be a callable taking the backend name and doing the
     full dispatch (tile selection, chaos hook, kernel call) for that rung.
-    The first successful rung's result is returned. Under
-    ``REPRO_STRICT_BACKEND=1`` the first failure raises unchanged. If
+    The first successful rung's result is returned. When
+    ``strict_backend()`` holds (a TPU, or ``REPRO_STRICT_BACKEND=1``) the
+    first failure raises unchanged. If
     every rung fails, the *original* backend's error is raised with the
     last rung's appended as context.
     """

@@ -8,7 +8,9 @@ whole APSP fuses into ONE pallas_call: the grid's iteration axis revisits
 the same block while a VMEM scratch carries the evolving distance matrix —
 zero intermediate HBM traffic.
 
-The inner product is the same VPU broadcast-add-min loop as minplus.py.
+The inner product is the same VPU broadcast-add-min loop as minplus.py:
+row k is read from the VMEM ref on the sublane axis, column k is a
+lane-masked reduction of the loaded matrix.
 ops.apsp falls back to iterated minplus_matmul for matrices beyond the VMEM
 budget.
 
@@ -19,6 +21,8 @@ is a pure-XLA min-plus doubling instead. ``REPRO_APSP_BACKEND`` overrides
 (``pallas`` | ``pallas_interpret`` | ``xla`` | ``pallas_tiled`` |
 ``pallas_tiled_interpret`` | ``xla_blocked``); the legacy
 ``REPRO_PALLAS_INTERPRET=0`` still forces compiled Pallas everywhere.
+On a TPU the compiled kernel is the only path: a failure to compile or
+dispatch raises (see ``faults.harness.strict_backend``).
 
 Large-n tier (ISSUE 6): the fused kernel carries the whole [n, n] matrix in
 VMEM scratch and ``apsp_xla`` materializes [B, n, n, n] per squaring, both
@@ -40,6 +44,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..utils import env as _env
+from .minplus import lane_column
 from .ref import BIG
 
 # [n, n] f32 scratch must fit comfortably in ~16 MiB VMEM with headroom.
@@ -73,19 +78,19 @@ def _apsp_kernel(d_ref, o_ref, acc_ref):
 
     @pl.when(it == 0)
     def _load():
-        acc_ref[...] = d_ref[0]
+        acc_ref[...] = d_ref[...]
 
     d = acc_ref[...]
     n = d.shape[0]
 
     def body(k, acc):
-        return jnp.minimum(acc, d[:, k][:, None] + d[k, :][None, :])
+        return jnp.minimum(acc, lane_column(d, k) + acc_ref[pl.ds(k, 1), :])
 
     acc_ref[...] = jax.lax.fori_loop(0, n, body, d)
 
     @pl.when(it == pl.num_programs(1) - 1)
     def _flush():
-        o_ref[0] = acc_ref[...]
+        o_ref[...] = acc_ref[...]
 
 
 @functools.partial(jax.jit, static_argnames=("n_iters",))
@@ -111,8 +116,8 @@ def apsp_pallas(d: jax.Array, n_iters: int, *, interpret: bool = True
     return pl.pallas_call(
         _apsp_kernel,
         grid=(B, n_iters),
-        in_specs=[pl.BlockSpec((1, n, n), lambda b, i: (b, 0, 0))],
-        out_specs=pl.BlockSpec((1, n, n), lambda b, i: (b, 0, 0)),
+        in_specs=[pl.BlockSpec((None, n, n), lambda b, i: (b, 0, 0))],
+        out_specs=pl.BlockSpec((None, n, n), lambda b, i: (b, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, n, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((n, n), jnp.float32)],
         interpret=interpret,
@@ -174,18 +179,17 @@ def _apsp_square_kernel(tile: int, a_ref, b_ref, o_ref, acc_ref):
     def _init():
         acc_ref[...] = jnp.full(acc_ref.shape, BIG, acc_ref.dtype)
 
-    a = a_ref[0]                                      # [T, n] row slab
-    b = b_ref[0]                                      # [T, n] k slab
+    a = a_ref[...]                                    # [T, n] row slab
 
     def body(j, acc):
         k = kt * tile + j
-        return jnp.minimum(acc, a[:, k][:, None] + b[j, :][None, :])
+        return jnp.minimum(acc, lane_column(a, k) + b_ref[pl.ds(j, 1), :])
 
     acc_ref[...] = jax.lax.fori_loop(0, tile, body, acc_ref[...])
 
     @pl.when(kt == pl.num_programs(2) - 1)
     def _flush():
-        o_ref[0] = acc_ref[...]
+        o_ref[...] = acc_ref[...]
 
 
 @functools.partial(jax.jit,
@@ -206,9 +210,12 @@ def apsp_pallas_tiled(d: jax.Array, n_iters: int, tile: int, *,
         return pl.pallas_call(
             kernel,
             grid=(B, nt, nt),
-            in_specs=[pl.BlockSpec((1, tile, n), lambda b, i, k: (b, i, 0)),
-                      pl.BlockSpec((1, tile, n), lambda b, i, k: (b, k, 0))],
-            out_specs=pl.BlockSpec((1, tile, n), lambda b, i, k: (b, i, 0)),
+            in_specs=[pl.BlockSpec((None, tile, n),
+                                   lambda b, i, k: (b, i, 0)),
+                      pl.BlockSpec((None, tile, n),
+                                   lambda b, i, k: (b, k, 0))],
+            out_specs=pl.BlockSpec((None, tile, n),
+                                   lambda b, i, k: (b, i, 0)),
             out_shape=jax.ShapeDtypeStruct((B, n, n), jnp.float32),
             scratch_shapes=[pltpu.VMEM((tile, n), jnp.float32)],
             interpret=interpret,

@@ -6,9 +6,9 @@ atomic scatter-add; TPUs have no fast scatter atomics, so we rebuild the
 update as an MXU matmul over one-hot masks generated *inside* the kernel from
 iota comparisons (DESIGN.md §2 — nothing is materialized in HBM):
 
-    mask_cur[p, u] = [cur[p] == u]                   [bp, n]
-    mask_amt[p, v] = amount[p] * [nxt[p] == v]       [bp, n]
-    out += mask_curᵀ @ mask_amt                      [n, n]  (MXU)
+    mask_cur[u, p] = [cur[p] == u]                   [n, bp]
+    mask_amt[v, p] = amount[p] * [nxt[p] == v]       [n, bp]
+    out += mask_cur @ mask_amtᵀ                      [n, n]  (MXU)
 
 Grid: (batch, P/bp) with the pair axis innermost; the [n, n] output block is
 revisited across pair-blocks and accumulated in place (initialized from the
@@ -32,19 +32,20 @@ def _flow_kernel(cur_ref, nxt_ref, amt_ref, fin_ref, o_ref):
     def _init():
         o_ref[...] = fin_ref[...]
 
-    cur = cur_ref[0]                                  # [bp] int32
-    nxt = nxt_ref[0]                                  # [bp] int32
-    amt = amt_ref[0].astype(jnp.float32)              # [bp]
+    cur = cur_ref[...]                                # [1, bp] int32
+    nxt = nxt_ref[...]                                # [1, bp] int32
+    amt = amt_ref[...].astype(jnp.float32)            # [1, bp]
     n = o_ref.shape[-1]
-    bp = cur.shape[0]
-    iota = jax.lax.broadcasted_iota(jnp.int32, (bp, n), 1)
-    mask_cur = (iota == cur[:, None]).astype(jnp.float32)
-    mask_amt = jnp.where(iota == nxt[:, None], amt[:, None], 0.0)
+    bp = cur.shape[1]
+    iota = jax.lax.broadcasted_iota(jnp.int32, (n, bp), 0)
+    mask_cur = (iota == cur).astype(jnp.float32)      # [u, p]
+    mask_amt = jnp.where(iota == nxt, amt, 0.0)       # [v, p]
     contrib = jax.lax.dot_general(
         mask_cur, mask_amt,
-        dimension_numbers=(((0,), (0,)), ((), ())),   # contract over pairs
+        dimension_numbers=(((1,), (1,)), ((), ())),   # contract over pairs
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)
-    o_ref[0] = o_ref[0] + contrib.astype(o_ref.dtype)
+    o_ref[...] = o_ref[...] + contrib.astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("bp", "interpret"))
@@ -52,20 +53,19 @@ def flow_accum_pallas(flow: jax.Array, cur: jax.Array, nxt: jax.Array,
                       amount: jax.Array, *, bp: int = 512,
                       interpret: bool = True) -> jax.Array:
     """Batched flow accumulation. flow: [B, n, n]; cur/nxt/amount: [B, P]
-    with P a multiple of bp (ops.py pads with amount == 0)."""
+    with P a multiple of bp (ops.py pads with amount == 0). The pair
+    vectors travel as [B, 1, P] so each block is a lane-major [1, bp] row
+    (a multiple of 128 lanes, or the whole padded pair axis)."""
     B, n, _ = flow.shape
     P = cur.shape[1]
     grid = (B, P // bp)
+    pairs = pl.BlockSpec((None, 1, bp), lambda b_, p: (b_, 0, p))
+    pane = pl.BlockSpec((None, n, n), lambda b_, p: (b_, 0, 0))
     return pl.pallas_call(
         _flow_kernel,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, bp), lambda b_, p: (b_, p)),
-            pl.BlockSpec((1, bp), lambda b_, p: (b_, p)),
-            pl.BlockSpec((1, bp), lambda b_, p: (b_, p)),
-            pl.BlockSpec((1, n, n), lambda b_, p: (b_, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, n, n), lambda b_, p: (b_, 0, 0)),
+        in_specs=[pairs, pairs, pairs, pane],
+        out_specs=pane,
         out_shape=jax.ShapeDtypeStruct((B, n, n), flow.dtype),
         interpret=interpret,
-    )(cur, nxt, amount, flow)
+    )(cur[:, None, :], nxt[:, None, :], amount[:, None, :], flow)

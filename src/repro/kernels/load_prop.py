@@ -30,16 +30,19 @@ body in Python). ``REPRO_LOAD_PROP_BACKEND`` overrides (``pallas`` |
 ``pallas_interpret`` | ``xla`` | ``pallas_tiled`` |
 ``pallas_tiled_interpret`` | ``xla_blocked``); the legacy
 ``REPRO_PALLAS_INTERPRET=0`` still forces compiled Pallas everywhere.
+On a TPU the compiled kernel is the only path: a failure to compile or
+dispatch raises (see ``faults.harness.strict_backend``).
 
 Large-n tier (ISSUE 6): the fused kernel keeps the whole [n, n] state pane
 in VMEM and the XLA loop materializes the [B, n, n, n] one-hot, so both
 blow up past n ≈ 128–256. The ``*_tiled`` / ``xla_blocked`` variants
-exploit that the propagation is *independent per destination row*: they
-stream ``[tile, n]`` destination slabs of the next-hop table and load
-matrix (2-D grid batch × destination-tile for Pallas, a ``lax.scan`` over
-destination tiles for XLA), accumulating the shared flow matrix across
-tiles. Per-tile working set is O(tile · n) state + O(B · tile · n²)
-transient one-hot for XLA — bounded by the tile size regardless of n.
+exploit that the propagation is *independent per destination*: they
+stream destination slabs of the next-hop table and load matrix (a 2-D
+grid batch × destination-tile of src-major ``[n, tile]`` lane slabs for
+Pallas, a ``lax.scan`` over ``[tile, n]`` slabs for XLA), accumulating
+the shared flow matrix across tiles. Per-tile working set is
+O(tile · n) state + O(B · tile · n²) transient one-hot for XLA —
+bounded by the tile size regardless of n.
 ``kernels.ops.load_propagate`` auto-switches to the tiled variant above
 ``REPRO_LOAD_PROP_FUSED_N`` (default 160) nodes; ``REPRO_LOAD_PROP_TILE``
 overrides the auto-chosen tile.
@@ -51,8 +54,14 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ..utils import env as _env
+
+# The one-hot contractions of the XLA loops run on a TPU's MXU, whose
+# default f32 precision rounds the loads to bf16; HIGHEST keeps them f32
+# (no effect on the CPU).
+_EXACT = jax.lax.Precision.HIGHEST
 
 LOAD_PROP_BACKENDS = ("pallas", "pallas_interpret", "xla",
                       "pallas_tiled", "pallas_tiled_interpret", "xla_blocked")
@@ -129,7 +138,8 @@ def load_prop_xla(next_hop: jax.Array, load0: jax.Array, max_hops: int,
         load, total = state
         total = total + load
         load = jnp.where(offdiag,
-                         jnp.einsum("bduv,bdu->bdv", oh, load), 0.0)
+                         jnp.einsum("bduv,bdu->bdv", oh, load,
+                                    precision=_EXACT), 0.0)
         return load, total
 
     def still_active(state):
@@ -137,52 +147,56 @@ def load_prop_xla(next_hop: jax.Array, load0: jax.Array, max_hops: int,
 
     _, total = hop_loop(step, (load0, jnp.zeros_like(load0)), max_hops,
                         adaptive, still_active)
-    flow = jnp.einsum("bduv,bdu->buv", oh, total)
+    flow = jnp.einsum("bduv,bdu->buv", oh, total, precision=_EXACT)
     return total, flow
 
 
-def _load_prop_kernel(max_hops: int, nht_ref, l0_ref, w_ref, f_ref):
+def _load_prop_kernel(max_hops: int, nh_ref, l0_ref, w_ref, f_ref, ld_ref):
     """One design per grid step: the whole propagation plus the flow
     contraction, with every one-hot regenerated from iota comparisons
-    inside VMEM (the [n, n, n] tensor never exists)."""
-    n = l0_ref.shape[-1]
-    nhT = nht_ref[0]                                            # [d, u]
-    viota = jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
-    diota = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
-    offdiag = viota != diota
-    load0 = jnp.where(offdiag, l0_ref[0], 0.0)
+    inside VMEM (the [n, n, n] tensor never exists).
 
-    def propagate(load):
-        # new[d, v] = Σ_u [nhT[d, u] = v] · load[d, u] — the scatter over v
-        # as a broadcast-compare-add sweep over source columns (the same
-        # dynamic-column idiom as the fused APSP kernel).
+    State is src-major — ld_ref[u, d] is the load at u destined for d —
+    so the per-source loop reads row u of the next-hop table and of the
+    load from their refs on the sublane axis (``pl.ds``). Mosaic refuses a
+    slice of a loaded value at a traced lane index, which the dest-major
+    layout needed. Outputs are src-major too: W^T[u, d] and flow^T[v, u]."""
+    n = l0_ref.shape[-1]
+    viota = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+    liota = jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
+    offdiag = viota != liota
+    ld_ref[...] = jnp.where(offdiag, l0_ref[...], 0.0)
+
+    def propagate():
+        # new[v, d] = Σ_u [nh[u, d] = v] · load[u, d]: the scatter over v
+        # as a broadcast-compare-add sweep over source rows.
         def body(u, acc):
-            idx = nhT[:, u]                                     # [d]
-            lu = load[:, u]                                     # [d]
-            return acc + jnp.where(viota == idx[:, None],
-                                   lu[:, None], 0.0)
+            idx = nh_ref[pl.ds(u, 1), :]                        # [1, d]
+            lu = ld_ref[pl.ds(u, 1), :]                         # [1, d]
+            return acc + jnp.where(viota == idx, lu, 0.0)
 
         return jax.lax.fori_loop(0, n, body,
                                  jnp.zeros((n, n), jnp.float32))
 
-    def hop(_, state):
-        load, total = state
-        total = total + load
-        return jnp.where(offdiag, propagate(load), 0.0), total
+    def hop(_, total):
+        total = total + ld_ref[...]
+        ld_ref[...] = jnp.where(offdiag, propagate(), 0.0)
+        return total
 
-    _, total = jax.lax.fori_loop(
-        0, max_hops, hop, (load0, jnp.zeros((n, n), jnp.float32)))
-    w_ref[0] = total
+    w_ref[...] = jax.lax.fori_loop(0, max_hops, hop,
+                                   jnp.zeros((n, n), jnp.float32))
 
-    # flow[u, v] = Σ_d [nhT[d, u] = v] · W[d, u]
+    # flow^T[v, u] = Σ_d [nh[u, d] = v] · W^T[u, d]: column u of flow^T is
+    # a lane reduction, placed by a lane-iota select.
     def f_body(u, acc):
-        mask = viota == nhT[:, u][:, None]                      # [d, v]
-        row = jnp.sum(jnp.where(mask, total[:, u][:, None], 0.0),
-                      axis=0)                                   # [v]
-        return acc + jnp.where(diota == u, row[None, :], 0.0)
+        idx = nh_ref[pl.ds(u, 1), :]                            # [1, d]
+        wu = w_ref[pl.ds(u, 1), :]                              # [1, d]
+        col = jnp.sum(jnp.where(viota == idx, wu, 0.0), axis=1,
+                      keepdims=True)                            # [v, 1]
+        return jnp.where(liota == u, col, acc)
 
-    f_ref[0] = jax.lax.fori_loop(0, n, f_body,
-                                 jnp.zeros((n, n), jnp.float32))
+    f_ref[...] = jax.lax.fori_loop(0, n, f_body,
+                                   jnp.zeros((n, n), jnp.float32))
 
 
 @functools.partial(jax.jit, static_argnames=("max_hops", "interpret"))
@@ -193,19 +207,20 @@ def load_prop_pallas(next_hop: jax.Array, load0: jax.Array, max_hops: int,
     (padding rows/cols must be self-loops); load0: [B, n, n] f32 dest-major
     with zero padding. Returns (W dest-major, directed flow)."""
     B, n, _ = next_hop.shape
-    nhT = next_hop.swapaxes(-1, -2).astype(jnp.int32)
     kernel = functools.partial(_load_prop_kernel, max_hops)
-    return pl.pallas_call(
+    block = pl.BlockSpec((None, n, n), lambda b: (b, 0, 0))
+    w_t, f_t = pl.pallas_call(
         kernel,
         grid=(B,),
-        in_specs=[pl.BlockSpec((1, n, n), lambda b: (b, 0, 0)),
-                  pl.BlockSpec((1, n, n), lambda b: (b, 0, 0))],
-        out_specs=[pl.BlockSpec((1, n, n), lambda b: (b, 0, 0)),
-                   pl.BlockSpec((1, n, n), lambda b: (b, 0, 0))],
+        in_specs=[block, block],
+        out_specs=[block, block],
         out_shape=[jax.ShapeDtypeStruct((B, n, n), jnp.float32),
                    jax.ShapeDtypeStruct((B, n, n), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((n, n), jnp.float32)],
         interpret=interpret,
-    )(nhT, load0.astype(jnp.float32))
+    )(next_hop.astype(jnp.int32),
+      load0.astype(jnp.float32).swapaxes(-1, -2))
+    return w_t.swapaxes(-1, -2), f_t.swapaxes(-1, -2)
 
 
 # --------------------------------------------------------------------------
@@ -213,12 +228,13 @@ def load_prop_pallas(next_hop: jax.Array, load0: jax.Array, max_hops: int,
 # --------------------------------------------------------------------------
 
 def pick_tile(n: int, batch: int, budget_elems: int = 1 << 25) -> int:
-    """Auto tile size for the blocked variants: the largest power of two
-    ≤ 128 whose transient working set (batch · tile · n² elements for the
-    XLA one-hot) stays under ``budget_elems`` (default 2^25 ≈ 128 MB f32).
-    Floor of 8 keeps the sublane dimension tiling-friendly. Powers of two
-    always divide the 128-lane padding the Pallas path applies, so the
-    grid never needs a ragged last tile there."""
+    """Auto tile size for the XLA-blocked variants (and the tiled APSP
+    kernel's row slabs): the largest power of two ≤ 128 whose transient
+    working set (batch · tile · n² elements for the XLA one-hot) stays
+    under ``budget_elems`` (default 2^25 ≈ 128 MB f32). Floor of 8 keeps
+    the sublane dimension tiling-friendly. Powers of two always divide the
+    128-lane padding the Pallas paths apply, so the grid never needs a
+    ragged last tile there."""
     tile = 128
     while tile > 8 and batch * tile * n * n > budget_elems:
         tile //= 2
@@ -259,7 +275,8 @@ def load_prop_xla_blocked(next_hop: jax.Array, load0: jax.Array,
             load, total = state
             total = total + load
             load = jnp.where(offdiag,
-                             jnp.einsum("btuv,btu->btv", oh, load), 0.0)
+                             jnp.einsum("btuv,btu->btv", oh, load,
+                                        precision=_EXACT), 0.0)
             return load, total
 
         def still_active(state):
@@ -267,7 +284,8 @@ def load_prop_xla_blocked(next_hop: jax.Array, load0: jax.Array,
 
         _, total = hop_loop(step, (load0s, jnp.zeros_like(load0s)),
                             max_hops, adaptive, still_active)
-        return flow + jnp.einsum("btuv,btu->buv", oh, total), total
+        return flow + jnp.einsum("btuv,btu->buv", oh, total,
+                                 precision=_EXACT), total
 
     flow0 = jnp.zeros((B, n, n), jnp.float32)
     flow, w_t = jax.lax.scan(
@@ -276,51 +294,52 @@ def load_prop_xla_blocked(next_hop: jax.Array, load0: jax.Array,
     return w, flow
 
 
-def _load_prop_tiled_kernel(max_hops: int, nht_ref, l0_ref, w_ref, f_ref):
-    """One (design, destination-tile) pair per grid step: the VMEM working
-    set is two [tile, n] slabs plus the shared [n, n] flow pane, which is
-    revisited across the inner (tile) grid axis and accumulated in place."""
+def _load_prop_tiled_kernel(max_hops: int, nh_ref, l0_ref, w_ref, f_ref,
+                            ld_ref):
+    """One (design, destination-tile) pair per grid step, in the fused
+    kernel's src-major layout: the destination tile is a [n, tile] lane
+    slab of the next-hop table and load, and the shared flow^T pane [n, n]
+    is revisited across the inner (tile) grid axis and accumulated in
+    place. Compiled, ``tile`` must be a multiple of the 128-lane width."""
     t = pl.program_id(1)
-    tile, n = l0_ref.shape[-2], l0_ref.shape[-1]
-    nhT = nht_ref[0]                                            # [d, u] slab
-    viota = jax.lax.broadcasted_iota(jnp.int32, (tile, n), 1)
-    dglob = jax.lax.broadcasted_iota(jnp.int32, (tile, n), 0) + t * tile
+    n, tile = l0_ref.shape
+    viota = jax.lax.broadcasted_iota(jnp.int32, (n, tile), 0)
+    dglob = jax.lax.broadcasted_iota(jnp.int32, (n, tile), 1) + t * tile
     offdiag = viota != dglob
-    load0 = jnp.where(offdiag, l0_ref[0], 0.0)
+    ld_ref[...] = jnp.where(offdiag, l0_ref[...], 0.0)
 
-    def propagate(load):
+    def propagate():
         def body(u, acc):
-            idx = nhT[:, u]                                     # [d]
-            lu = load[:, u]                                     # [d]
-            return acc + jnp.where(viota == idx[:, None],
-                                   lu[:, None], 0.0)
+            idx = nh_ref[pl.ds(u, 1), :]                        # [1, tile]
+            lu = ld_ref[pl.ds(u, 1), :]                         # [1, tile]
+            return acc + jnp.where(viota == idx, lu, 0.0)
 
         return jax.lax.fori_loop(0, n, body,
-                                 jnp.zeros((tile, n), jnp.float32))
+                                 jnp.zeros((n, tile), jnp.float32))
 
-    def hop(_, state):
-        load, total = state
-        total = total + load
-        return jnp.where(offdiag, propagate(load), 0.0), total
+    def hop(_, total):
+        total = total + ld_ref[...]
+        ld_ref[...] = jnp.where(offdiag, propagate(), 0.0)
+        return total
 
-    _, total = jax.lax.fori_loop(
-        0, max_hops, hop, (load0, jnp.zeros((tile, n), jnp.float32)))
-    w_ref[0] = total
+    w_ref[...] = jax.lax.fori_loop(0, max_hops, hop,
+                                   jnp.zeros((n, tile), jnp.float32))
 
     @pl.when(t == 0)
     def _init():
-        f_ref[0] = jnp.zeros_like(f_ref[0])
+        f_ref[...] = jnp.zeros_like(f_ref)
 
-    # this tile's flow contribution: flow[u, v] += Σ_{d∈tile} 1[nhT[d,u]=v]·W
-    uiota = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+    # this tile's contribution: flow^T[v, u] += Σ_{d∈tile} [nh[u,d]=v]·W^T
+    uiota = jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
 
     def f_body(u, acc):
-        mask = viota == nhT[:, u][:, None]                      # [d, v]
-        row = jnp.sum(jnp.where(mask, total[:, u][:, None], 0.0),
-                      axis=0)                                   # [v]
-        return acc + jnp.where(uiota == u, row[None, :], 0.0)
+        idx = nh_ref[pl.ds(u, 1), :]                            # [1, tile]
+        wu = w_ref[pl.ds(u, 1), :]                              # [1, tile]
+        col = jnp.sum(jnp.where(viota == idx, wu, 0.0), axis=1,
+                      keepdims=True)                            # [v, 1]
+        return jnp.where(uiota == u, col, acc)
 
-    f_ref[0] = f_ref[0] + jax.lax.fori_loop(
+    f_ref[...] = f_ref[...] + jax.lax.fori_loop(
         0, n, f_body, jnp.zeros((n, n), jnp.float32))
 
 
@@ -331,25 +350,26 @@ def load_prop_pallas_tiled(next_hop: jax.Array, load0: jax.Array,
                            interpret: bool = True
                            ) -> tuple[jax.Array, jax.Array]:
     """Destination-tiled fused load propagation: grid (batch × dest-tile)
-    streaming [tile, n] slabs through VMEM. Same contract as
+    streaming [n, tile] src-major slabs through VMEM. Same contract as
     ``load_prop_pallas`` (self-loop padding rows, zero-padded load); the
     destination axis must additionally be a multiple of ``tile``, which
-    ``ops.load_propagate`` guarantees by picking power-of-two tiles that
-    divide the 128-lane padding."""
+    ``ops.load_propagate`` guarantees (128-lane tiles of the 128-lane
+    padding)."""
     B, n, _ = next_hop.shape
     if n % tile:
         raise ValueError(f"tile {tile} must divide padded n {n}")
     nt = n // tile
-    nhT = next_hop.swapaxes(-1, -2).astype(jnp.int32)
     kernel = functools.partial(_load_prop_tiled_kernel, max_hops)
-    return pl.pallas_call(
+    slab = pl.BlockSpec((None, n, tile), lambda b, t: (b, 0, t))
+    w_t, f_t = pl.pallas_call(
         kernel,
         grid=(B, nt),
-        in_specs=[pl.BlockSpec((1, tile, n), lambda b, t: (b, t, 0)),
-                  pl.BlockSpec((1, tile, n), lambda b, t: (b, t, 0))],
-        out_specs=[pl.BlockSpec((1, tile, n), lambda b, t: (b, t, 0)),
-                   pl.BlockSpec((1, n, n), lambda b, t: (b, 0, 0))],
+        in_specs=[slab, slab],
+        out_specs=[slab, pl.BlockSpec((None, n, n), lambda b, t: (b, 0, 0))],
         out_shape=[jax.ShapeDtypeStruct((B, n, n), jnp.float32),
                    jax.ShapeDtypeStruct((B, n, n), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((n, tile), jnp.float32)],
         interpret=interpret,
-    )(nhT, load0.astype(jnp.float32))
+    )(next_hop.astype(jnp.int32),
+      load0.astype(jnp.float32).swapaxes(-1, -2))
+    return w_t.swapaxes(-1, -2), f_t.swapaxes(-1, -2)

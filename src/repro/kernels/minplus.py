@@ -4,7 +4,8 @@ The latency proxy's all-pairs-shortest-path step is a min-plus matmul
 (DESIGN.md §2): ``out[i,j] = min_k a[i,k] + b[k,j]``. The MXU cannot evaluate
 a (min, +) semiring, so this is a VPU kernel: each [bm, bn] output tile is
 accumulated in a VMEM scratch buffer while k-blocks stream through VMEM, with
-an inner fori_loop over the k-block (one [bm, bn] broadcast-add-min per k) to
+an inner fori_loop over the k-block (one [bm, bn] broadcast-add-min per k:
+row k of b read from its ref, column k of a as a lane-masked reduction) to
 keep the live working set at O(bm*bn + bm*bk + bk*bn) — never the
 O(bm*bk*bn) cube a naive broadcast would materialize.
 
@@ -28,6 +29,14 @@ from jax.experimental.pallas import tpu as pltpu
 from .ref import BIG
 
 
+def lane_column(x: jax.Array, k) -> jax.Array:
+    """Column k of a loaded [r, c] value as an [r, 1] vector: a lane-masked
+    sum (exact — one nonzero summand). Mosaic refuses ``x[:, k]`` at a
+    traced lane index; rows come straight from refs via ``pl.ds``."""
+    lanes = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    return jnp.sum(jnp.where(lanes == k, x, 0.0), axis=1, keepdims=True)
+
+
 def _minplus_kernel(a_ref, b_ref, o_ref, acc_ref):
     k = pl.program_id(3)
 
@@ -35,18 +44,18 @@ def _minplus_kernel(a_ref, b_ref, o_ref, acc_ref):
     def _init():
         acc_ref[...] = jnp.full(acc_ref.shape, BIG, acc_ref.dtype)
 
-    a = a_ref[0].astype(acc_ref.dtype)          # [bm, bk]
-    b = b_ref[0].astype(acc_ref.dtype)          # [bk, bn]
+    a = a_ref[...].astype(acc_ref.dtype)        # [bm, bk]
     bk = a.shape[1]
 
     def body(kk, acc):
-        return jnp.minimum(acc, a[:, kk][:, None] + b[kk, :][None, :])
+        row = b_ref[pl.ds(kk, 1), :].astype(acc.dtype)          # [1, bn]
+        return jnp.minimum(acc, lane_column(a, kk) + row)
 
     acc_ref[...] = jax.lax.fori_loop(0, bk, body, acc_ref[...])
 
     @pl.when(k == pl.num_programs(3) - 1)
     def _flush():
-        o_ref[0] = acc_ref[...].astype(o_ref.dtype)
+        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
@@ -65,10 +74,11 @@ def minplus_pallas(a: jax.Array, b: jax.Array, *, bm: int = 128,
         _minplus_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, bm, bk), lambda b_, i, j, k: (b_, i, k)),
-            pl.BlockSpec((1, bk, bn), lambda b_, i, j, k: (b_, k, j)),
+            pl.BlockSpec((None, bm, bk), lambda b_, i, j, k: (b_, i, k)),
+            pl.BlockSpec((None, bk, bn), lambda b_, i, j, k: (b_, k, j)),
         ],
-        out_specs=pl.BlockSpec((1, bm, bn), lambda b_, i, j, k: (b_, i, j)),
+        out_specs=pl.BlockSpec((None, bm, bn),
+                               lambda b_, i, j, k: (b_, i, j)),
         out_shape=jax.ShapeDtypeStruct((B, M, N), a.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,

@@ -1,7 +1,9 @@
 """Jit'd public wrappers around the Pallas kernels: shape padding, dtype
-handling, 2D/batched dispatch. On this CPU container the kernels execute in
-interpret mode (the kernel body runs in Python via the Pallas interpreter);
-on real TPUs set ``REPRO_PALLAS_INTERPRET=0`` to compile them for hardware.
+handling, 2D/batched dispatch. On a TPU the kernels are compiled for the
+chip; elsewhere they run in interpret mode (the kernel body runs through
+the Pallas interpreter). ``REPRO_PALLAS_INTERPRET`` forces either mode.
+On a TPU a kernel that fails to compile or dispatch raises: the backend
+fallback ladder (``faults.harness.run_with_fallback``) is off there.
 """
 from __future__ import annotations
 
@@ -29,8 +31,19 @@ def _note_dispatch(op: str, backend: str, tile: int | None,
                      promoted=promoted, n=n).inc()
 
 
-def _interpret() -> bool:
-    return _env.get_str("REPRO_PALLAS_INTERPRET") != "0"
+def interpret_mode() -> bool:
+    """Interpret Pallas kernels off the TPU and compile them on it, unless
+    ``REPRO_PALLAS_INTERPRET`` asks for one mode explicitly."""
+    forced = _env.get_str("REPRO_PALLAS_INTERPRET")
+    if forced:
+        return forced != "0"
+    return jax.default_backend() != "tpu"
+
+
+# The tiled load-propagation kernel lays destinations along lanes, so its
+# compiled tile is a whole number of 128-lane vregs (``pick_tile`` sizes
+# the XLA one-hot, which has no such constraint).
+LANE_TILE = 128
 
 
 def _round_up(x: int, m: int) -> int:
@@ -74,7 +87,7 @@ def minplus_matmul(a: jax.Array, b: jax.Array, bm: int | None = None,
     Mp, Kp, Np = _round_up(M, bm), _round_up(K, bk), _round_up(N, bn)
     ap = _set_block(jnp.full((B, Mp, Kp), BIG, jnp.float32), a)
     bp_ = _set_block(jnp.full((B, Kp, Np), BIG, jnp.float32), b)
-    out = minplus_pallas(ap, bp_, bm=bm, bn=bn, bk=bk, interpret=_interpret())
+    out = minplus_pallas(ap, bp_, bm=bm, bn=bn, bk=bk, interpret=interpret_mode())
     out = out[:, :M, :N]
     return out[0] if squeeze else out
 
@@ -100,9 +113,22 @@ def flow_accumulate(flow: jax.Array, cur: jax.Array, nxt: jax.Array,
     cu = _set_block(jnp.zeros((B, Pp), jnp.int32), cur)
     nx = _set_block(jnp.zeros((B, Pp), jnp.int32), nxt)
     am = _set_block(jnp.zeros((B, Pp), jnp.float32), amount)
-    out = flow_accum_pallas(fl, cu, nx, am, bp=bp, interpret=_interpret())
+    out = flow_accum_pallas(fl, cu, nx, am, bp=bp, interpret=interpret_mode())
     out = out[:, :n, :n].astype(flow.dtype)
     return out[0] if squeeze else out
+
+
+def load_prop_tile(backend: str, n: int, batch: int) -> int | None:
+    """The destination tile ``load_propagate`` runs ``backend`` with
+    (None for the untiled backends)."""
+    from .load_prop import pick_tile
+
+    pinned = _env.get_opt_int("REPRO_LOAD_PROP_TILE")
+    if backend == "xla_blocked":
+        return pinned or pick_tile(n, batch)
+    if backend in ("pallas_tiled", "pallas_tiled_interpret"):
+        return pinned or LANE_TILE
+    return None
 
 
 def load_propagate(next_hop: jax.Array, load0: jax.Array,
@@ -131,8 +157,10 @@ def load_propagate(next_hop: jax.Array, load0: jax.Array,
     backends are promoted to their destination-tiled twins
     (``pallas -> pallas_tiled``, ``xla -> xla_blocked``) so neither the
     whole-matrix VMEM pane nor the [B, n, n, n] one-hot ever materializes;
-    ``REPRO_LOAD_PROP_TILE`` pins the tile size (else auto via
-    ``load_prop.pick_tile``). ``adaptive`` (XLA backends only) swaps the
+    ``REPRO_LOAD_PROP_TILE`` pins the tile size (else ``LANE_TILE`` for
+    the tiled kernel and ``load_prop.pick_tile`` for ``xla_blocked``;
+    compiled, a pinned Pallas tile must be a multiple of 128).
+    ``adaptive`` (XLA backends only) swaps the
     fixed-length scan for a while_loop that stops at the batch's routed
     diameter — per destination slab in the blocked variant; the fused
     kernels always run the shape-stable ``max_hops`` bound (extra steps
@@ -143,7 +171,7 @@ def load_propagate(next_hop: jax.Array, load0: jax.Array,
     trace time and keep the backend baked into their compiled programs;
     set the variable before first use.
     """
-    from .load_prop import default_backend, pick_tile
+    from .load_prop import default_backend
     from ..faults.harness import maybe_chaos_fail, run_with_fallback
 
     if backend is None:
@@ -157,14 +185,12 @@ def load_propagate(next_hop: jax.Array, load0: jax.Array,
     if promoted:
         backend = promote[backend]
 
-    # A failed dispatch falls back down the ladder (pallas_tiled ->
-    # xla_blocked -> xla) unless REPRO_STRICT_BACKEND=1; the chaos hook
-    # injects failures for CI to prove the ladder keeps results green.
+    # Off the TPU a failed dispatch falls back down the ladder
+    # (pallas_tiled -> xla_blocked -> xla); on a TPU, or under
+    # REPRO_STRICT_BACKEND=1, it raises. The chaos hook injects failures
+    # for CI to prove the ladder keeps results green.
     def attempt(bk):
-        tile = None
-        if bk in ("xla_blocked", "pallas_tiled", "pallas_tiled_interpret"):
-            tile = (_env.get_opt_int("REPRO_LOAD_PROP_TILE")
-                    or pick_tile(n, batch))
+        tile = load_prop_tile(bk, n, batch)
         maybe_chaos_fail(bk)
         _note_dispatch("load_propagate", bk, tile, promoted, n)
         return _load_propagate(next_hop, load0, max_hops, adaptive, bk,
@@ -316,4 +342,5 @@ def _apsp(d: jax.Array, n_iters: int | None, backend: str,
 
 
 __all__ = ["minplus_matmul", "flow_accumulate", "apsp", "load_propagate",
+           "load_prop_tile",
            "minplus_ref", "flow_accumulate_ref", "BIG"]

@@ -408,6 +408,8 @@ def main(argv=None) -> int:
     p.add_argument("--quiet", action="store_true")
     args = p.parse_args(argv)
 
+    from ..utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.trace:
         enable_tracing()
 

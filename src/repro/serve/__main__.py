@@ -132,6 +132,8 @@ def main(argv=None) -> int:
                    help="exit once every submitted job is terminal")
     args = p.parse_args(argv)
 
+    from ..utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     service = SearchService(
         state_dir=args.state_dir, max_jobs=args.max_jobs,
         max_queued=args.max_queued,

@@ -38,7 +38,6 @@ _COMPILE_CACHE: dict = {}
 def jax_available() -> bool:
     try:
         import jax  # noqa: F401
-        from jax.experimental import enable_x64  # noqa: F401
         return True
     except Exception:
         return False
@@ -242,8 +241,8 @@ def _build_runner(shape_key):
 
 def run_batch_jax(sim, rates, cfg: SimConfig) -> list[SimStats]:
     """Execute ``FastSim.run_batch`` semantics on the XLA backend."""
+    import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     rates = [float(r) for r in rates]
     B = len(rates)
@@ -306,7 +305,7 @@ def run_batch_jax(sim, rates, cfg: SimConfig) -> list[SimStats]:
 
     shape_key = (B, bn, L, V, cap, psize, k_pad, nb_base)
     i32 = np.int32
-    with enable_x64():
+    with jax.enable_x64(True):
         fn = _build_runner(shape_key)
         consts = tuple(jnp.asarray(x) for x in (
             out_link, lbn_sp, net.link_fwd_delay.astype(i32),

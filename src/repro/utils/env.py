@@ -53,10 +53,10 @@ def _register(name: str, type: str, default, doc: str,
 
 # --- kernels ---------------------------------------------------------------
 _register(
-    "REPRO_PALLAS_INTERPRET", "str", "1",
-    "'1' (default) runs Pallas kernels through the interpreter (the CPU "
-    "container); '0' compiles them for hardware and makes compiled Pallas "
-    "the default kernel backend everywhere.")
+    "REPRO_PALLAS_INTERPRET", "str", None,
+    "Unset: Pallas kernels compile on a TPU and run in interpret mode "
+    "elsewhere. '1' forces interpret mode; '0' forces compilation and "
+    "makes compiled Pallas the default kernel backend everywhere.")
 _register(
     "REPRO_LOAD_PROP_BACKEND", "str", None,
     "Force the load-propagation backend (auto-selected per runtime when "
@@ -70,7 +70,8 @@ _register(
 _register(
     "REPRO_LOAD_PROP_TILE", "int", None,
     "Pin the destination-tile size of the tiled load-propagation variants "
-    "(auto via load_prop.pick_tile when unset).")
+    "(unset: 128 lanes for the Pallas kernel, load_prop.pick_tile for "
+    "xla_blocked; a compiled Pallas tile must be a multiple of 128).")
 _register(
     "REPRO_APSP_BACKEND", "str", None,
     "Force the APSP backend (auto-selected per runtime when unset).",
@@ -98,8 +99,9 @@ _register(
 # --- faults / graceful degradation -----------------------------------------
 _register(
     "REPRO_STRICT_BACKEND", "bool", "0",
-    "Disable the kernel-backend fallback ladder: a dispatch failure "
-    "raises instead of retrying on the next rung (faults/harness.py).")
+    "Disable the kernel-backend fallback ladder off the TPU (on a TPU it "
+    "is always off): a dispatch failure raises instead of retrying on the "
+    "next rung (faults/harness.py).")
 _register(
     "REPRO_CHAOS_BACKEND_FAIL", "str", None,
     "Comma-separated kernel backend names that fail on purpose at "

@@ -1,40 +1,21 @@
-"""Compatibility shims across supported jax versions (0.4.37+).
+"""The two JAX entry points the package shares across its meshes.
 
-* ``shard_map``: exported from ``jax`` at top level since 0.5; lives in
-  ``jax.experimental.shard_map`` on 0.4.x.
-* ``make_auto_mesh``: ``jax.make_mesh(..., axis_types=(AxisType.Auto, ...))``
-  on jax versions that have ``AxisType``; a plain ``jax.make_mesh`` (same
-  sharding behavior) on 0.4.x, which predates explicit axis types.
+* ``shard_map``: ``jax.shard_map`` (``check_vma=False`` turns off its
+  replication check).
+* ``make_auto_mesh``: ``jax.make_mesh`` with every axis ``AxisType.Auto``.
 """
 from __future__ import annotations
 
 import jax
-
-try:                                    # jax >= 0.5
-    from jax import shard_map as _shard_map
-    _UNCHECKED_KW = "check_vma"
-except ImportError:                     # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _UNCHECKED_KW = "check_rep"
-
-
-def shard_map(f=None, **kwargs):
-    """jax.shard_map with the replication-check kwarg renamed per version
-    (``check_vma`` on jax >= 0.5, ``check_rep`` on 0.4.x)."""
-    for alias in ("check_vma", "check_rep"):
-        if alias in kwargs and alias != _UNCHECKED_KW:
-            kwargs[_UNCHECKED_KW] = kwargs.pop(alias)
-    if f is None:
-        return lambda g: _shard_map(g, **kwargs)
-    return _shard_map(f, **kwargs)
+from jax import shard_map
 
 
 def make_auto_mesh(shape: tuple[int, ...], axes: tuple[str, ...],
                    devices=None) -> jax.sharding.Mesh:
     kwargs = {} if devices is None else {"devices": devices}
-    try:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
-                             **kwargs)
-    except (AttributeError, TypeError):
-        return jax.make_mesh(shape, axes, **kwargs)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         **kwargs)
+
+
+__all__ = ["shard_map", "make_auto_mesh"]
