@@ -53,6 +53,7 @@ from __future__ import annotations
 
 import functools
 import threading
+import time
 from collections import defaultdict
 from dataclasses import dataclass
 
@@ -149,6 +150,21 @@ class PendingGenomeEval:
         self._finisher = finisher
         self.arrays = arrays
         self._result: GenomeEvalResult | None = None
+        self._block_s = 0.0
+
+    @property
+    def block_s(self) -> float:
+        """Seconds ``result()`` spent waiting for the device outputs (the
+        ``genomes.block`` span), without the host reports."""
+        return self._block_s
+
+    def block(self) -> None:
+        """Wait until the device has produced ``arrays``; the finishers
+        call this where they first need the outputs."""
+        t0 = time.perf_counter()
+        with _span("genomes.block"):
+            jax.block_until_ready(self.arrays)
+        self._block_s += time.perf_counter() - t0
 
     def result(self) -> GenomeEvalResult:
         if self._finisher is not None:
@@ -708,15 +724,20 @@ class AdjacencyPipeline:
                 self._phyy, self._cphyx, self._cphyy, self._bw,
                 self._traffic, self._consts)
 
+        # the finisher runs from ``pending.result()``, after ``pending``
+        # below is bound
         def finish() -> GenomeEvalResult:
             with _span("genomes.finish", space="adjacency", pop=Pn):
-                reports = self._report_arrays(genomes, deg,
-                                              np.asarray(len_sum)[:Pn])
-                return GenomeEvalResult(latency=np.asarray(lat)[:Pn],
-                                        throughput=np.asarray(thr)[:Pn],
-                                        reports=reports)
+                pending.block()
+                with _span("genomes.reports"):
+                    reports = self._report_arrays(genomes, deg,
+                                                  np.asarray(len_sum)[:Pn])
+                    return GenomeEvalResult(latency=np.asarray(lat)[:Pn],
+                                            throughput=np.asarray(thr)[:Pn],
+                                            reports=reports)
 
-        return PendingGenomeEval(finish, (lat, thr, len_sum))
+        pending = PendingGenomeEval(finish, (lat, thr, len_sum))
+        return pending
 
     def evaluate(self, genomes: np.ndarray) -> GenomeEvalResult:
         """One fused jitted call for a whole (repaired) population."""
@@ -775,6 +796,7 @@ class AdjacencyPipeline:
 
         def finish() -> FaultGridResult:
             with _span("genomes.finish_faults", space="adjacency", pop=Pn):
+                pending.block()
                 reports = self._report_arrays(genomes, deg,
                                               np.asarray(len_sum)[:Pn])
                 return FaultGridResult(
@@ -783,7 +805,8 @@ class AdjacencyPipeline:
                     reachable_fraction=np.asarray(reach)[:Pn],
                     reports=reports)
 
-        return PendingGenomeEval(finish, (lat, thr, reach, len_sum))
+        pending = PendingGenomeEval(finish, (lat, thr, reach, len_sum))
+        return pending
 
     def evaluate_faults(self, genomes: np.ndarray, link_fail: np.ndarray,
                         node_fail: np.ndarray) -> FaultGridResult:
@@ -978,11 +1001,13 @@ class ParametricPipeline:
                 reports = ReportArrays(total_chiplet_area=cols[:, 0],
                                        interposer_area=cols[:, 1],
                                        power=cols[:, 2], cost=cols[:, 3])
+                pending.block()
                 return GenomeEvalResult(latency=np.asarray(lat)[:Pn],
                                         throughput=np.asarray(thr)[:Pn],
                                         reports=reports)
 
-        return PendingGenomeEval(finish)
+        pending = PendingGenomeEval(finish, (lat, thr))
+        return pending
 
     def evaluate(self, genomes: np.ndarray) -> GenomeEvalResult:
         return self.evaluate_async(genomes).result()

@@ -14,12 +14,16 @@ Design constraints (ISSUE 7):
 
 Export formats: JSONL (one span per line — the schema ``report.validate``
 checks) and the Chrome trace-event JSON that ``chrome://tracing`` and
-Perfetto (https://ui.perfetto.dev) load directly.
+Perfetto (https://ui.perfetto.dev) load directly. When JAX is loaded at
+``enable()`` time, every span is also a ``jax.profiler.TraceAnnotation``
+of the same name, so it lands on the host plane of any active JAX profile,
+on the device ops' clock.
 """
 from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -52,7 +56,7 @@ _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("_tracer", "_name", "_attrs", "_t0", "_depth")
+    __slots__ = ("_tracer", "_name", "_attrs", "_t0", "_depth", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: dict | None):
         self._tracer = tracer
@@ -71,11 +75,17 @@ class _Span:
         depth = getattr(local, "depth", 0)
         local.depth = depth + 1
         self._depth = depth
+        ann = self._tracer._annotation
+        self._ann = ann(self._name) if ann is not None else None
+        if self._ann is not None:
+            self._ann.__enter__()
         self._t0 = time.monotonic_ns()
         return self
 
     def __exit__(self, *exc):
         t1 = time.monotonic_ns()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         tracer = self._tracer
         tracer._local.depth = self._depth
         th = threading.current_thread()
@@ -99,6 +109,8 @@ class Tracer:
         self._local = threading.local()
         self._lock = threading.Lock()
         self.n_emitted = 0
+        # jax.profiler.TraceAnnotation, bound by enable() when JAX is loaded
+        self._annotation = None
         # monotonic origin + the wall time it corresponds to, so exported
         # timestamps are relative (t=0 at enable) but anchored for humans
         self._t0_ns = time.monotonic_ns()
@@ -107,6 +119,12 @@ class Tracer:
 
     # -- control ------------------------------------------------------------
     def enable(self, clear: bool = True) -> None:
+        """Start recording. If JAX is already imported, spans also enter a
+        ``jax.profiler.TraceAnnotation`` from here on (``obs`` never
+        imports JAX itself)."""
+        jax = sys.modules.get("jax")
+        self._annotation = (jax.profiler.TraceAnnotation
+                            if jax is not None else None)
         if clear:
             self.clear()
         self._t0_ns = time.monotonic_ns()

@@ -203,14 +203,16 @@ class PopulationEvaluator:
                     self.space, genomes, sc.link_fail, sc.node_fail)
             self.n_evals += len(genomes)
             return PendingPopulationEval(
-                lambda: self._finalize_faults(genomes, pending.result()))
+                lambda: self._finalize_faults(genomes, pending.result()),
+                pending)
         if self._use_device_path():
             with _span("opt.dispatch", path="device", evals=len(genomes)):
                 pending = self.engine.evaluate_genomes_async(self.space,
                                                              genomes)
             self.n_evals += len(genomes)
             return PendingPopulationEval(
-                lambda: self._finalize(genomes, pending.result(), None))
+                lambda: self._finalize(genomes, pending.result(), None),
+                pending)
         with _span("opt.dispatch", path="host", evals=len(genomes)):
             points = self.space.decode(genomes, start_index=self.n_evals)
             self.n_evals += len(points)
@@ -273,7 +275,17 @@ class PopulationEvaluator:
 class PendingPopulationEval(PendingGenomeEval):
     """In-flight population evaluation (the same memoized-finisher contract
     as ``PendingGenomeEval``); ``result()`` blocks on the device, builds
-    the constraint mask, and is idempotent."""
+    the constraint mask, and is idempotent. ``block_s`` is the device wait
+    of the engine's handle ``inner`` (0 on the host path, which evaluates
+    at dispatch)."""
+
+    def __init__(self, finisher, inner: PendingGenomeEval | None = None):
+        super().__init__(finisher)
+        self._inner = inner
+
+    @property
+    def block_s(self) -> float:
+        return self._inner.block_s if self._inner is not None else 0.0
 
 
 # ---------------------------------------------------------------------------
@@ -502,17 +514,22 @@ class EvolutionarySearch(OptimizerBase):
         if self.pop is None:
             return self.space.sample(self.rng, self.pop_size)
         pop = self.pop
-        ranks = nondominated_ranks(pop.latency, pop.throughput, pop.feasible)
-        crowd = crowding_distance(pop.latency, pop.throughput, ranks)
-        scores = _selection_scores(ranks, crowd)
-        pa = pop.genomes[tournament_select(scores, self.pop_size, self.rng)]
-        pb = pop.genomes[tournament_select(scores, self.pop_size, self.rng)]
-        cross = self.rng.random(self.pop_size) < self.crossover_prob
-        children = np.where(cross[:, None],
-                            uniform_crossover(pa, pb, self.rng), pa)
-        return self.space.repair(
-            mutate_genes(children, self.space.cardinalities,
-                         self.mutation_rate, self.rng))
+        with _span("opt.rank"):
+            ranks = nondominated_ranks(pop.latency, pop.throughput,
+                                       pop.feasible)
+            crowd = crowding_distance(pop.latency, pop.throughput, ranks)
+            scores = _selection_scores(ranks, crowd)
+        with _span("opt.vary"):
+            pa = pop.genomes[tournament_select(scores, self.pop_size,
+                                               self.rng)]
+            pb = pop.genomes[tournament_select(scores, self.pop_size,
+                                               self.rng)]
+            cross = self.rng.random(self.pop_size) < self.crossover_prob
+            children = np.where(cross[:, None],
+                                uniform_crossover(pa, pb, self.rng), pa)
+            children = mutate_genes(children, self.space.cardinalities,
+                                    self.mutation_rate, self.rng)
+        return self.space.repair(children)
 
     def finish_step(self, ev: EvaluatedPopulation,
                     ingest: bool = True) -> None:
@@ -525,13 +542,15 @@ class EvolutionarySearch(OptimizerBase):
         if ingest:
             self._ingest(ev)
         # (mu + lambda) environmental selection over parents + children
-        merged = _pop_apply(lambda a, b: np.concatenate([a, b]),
-                            self.pop, ev)
-        m_ranks = nondominated_ranks(merged.latency, merged.throughput,
-                                     merged.feasible)
-        m_crowd = crowding_distance(merged.latency, merged.throughput, m_ranks)
-        order = np.sort(np.lexsort((-m_crowd, m_ranks))[:self.pop_size])
-        self.pop = _pop_apply(lambda x: x[order], merged)
+        with _span("opt.select"):
+            merged = _pop_apply(lambda a, b: np.concatenate([a, b]),
+                                self.pop, ev)
+            m_ranks = nondominated_ranks(merged.latency, merged.throughput,
+                                         merged.feasible)
+            m_crowd = crowding_distance(merged.latency, merged.throughput,
+                                        m_ranks)
+            order = np.sort(np.lexsort((-m_crowd, m_ranks))[:self.pop_size])
+            self.pop = _pop_apply(lambda x: x[order], merged)
         self.generation += 1
 
 
@@ -587,9 +606,10 @@ class SimulatedAnnealing(OptimizerBase):
         if self.chains is None:
             self.chains = self.space.sample(self.rng, self.n_chains)
             return self.chains
-        self._proposals = self.space.repair(
-            mutate_genes(self.chains, self.space.cardinalities,
-                         self.mutation_rate, self.rng))
+        with _span("opt.vary"):
+            proposals = mutate_genes(self.chains, self.space.cardinalities,
+                                     self.mutation_rate, self.rng)
+        self._proposals = self.space.repair(proposals)
         return self._proposals
 
     def finish_step(self, ev: EvaluatedPopulation,
@@ -602,13 +622,15 @@ class SimulatedAnnealing(OptimizerBase):
             return
         # the accept gate draws AFTER the evaluation — still one shared RNG
         # stream, because finish_step always runs before the next begin_step
-        energy = self._energy(ev)
-        d = energy - self.energies
-        temp = max(self.temperature, 1e-12)
-        accept = (d < 0) | (self.rng.random(self.n_chains)
-                            < np.exp(-np.clip(d, 0, 700) / temp))
-        self.chains = np.where(accept[:, None], self._proposals, self.chains)
-        self.energies = np.where(accept, energy, self.energies)
+        with _span("opt.select"):
+            energy = self._energy(ev)
+            d = energy - self.energies
+            temp = max(self.temperature, 1e-12)
+            accept = (d < 0) | (self.rng.random(self.n_chains)
+                                < np.exp(-np.clip(d, 0, 700) / temp))
+            self.chains = np.where(accept[:, None], self._proposals,
+                                   self.chains)
+            self.energies = np.where(accept, energy, self.energies)
         self.generation += 1
 
 

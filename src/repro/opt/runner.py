@@ -143,11 +143,8 @@ class AsyncStepper:
             return False
         if self._pending is None:
             self._pending = opt.evaluator.dispatch(opt.begin_step())
-        t0 = time.perf_counter()
         with _span("opt.device_wait", generation=opt.generation):
-            ev = self._pending.result()
-        _metrics.counter("opt.async.wait_s").inc(time.perf_counter() - t0)
-        self._pending = None
+            ev = self._result()
         with _span("opt.generation", generation=opt.generation,
                    mode="async"):
             opt.finish_step(ev, ingest=False)
@@ -157,12 +154,19 @@ class AsyncStepper:
                 # the device computes through the entire deferred window
                 self._pending = opt.evaluator.dispatch(opt.begin_step())
         self._deferred = (ev, meta)
-        dt = time.perf_counter() - t_start
-        _metrics.histogram("opt.generation_s").observe(dt)
-        if dt > 0:
-            _metrics.histogram("opt.evals_per_s").observe(
-                len(ev.latency) / dt)
+        _metrics.histogram("opt.generation_s").observe(
+            time.perf_counter() - t_start)
         return True
+
+    def _result(self):
+        """The in-flight generation's results. Counts the time blocked on
+        the device (``genomes.block``, reports excluded) into
+        ``opt.async.wait_s`` and the results into ``opt.evals_completed``."""
+        ev = self._pending.result()
+        _metrics.counter("opt.async.wait_s").inc(self._pending.block_s)
+        _metrics.counter("opt.evals_completed").inc(len(ev.latency))
+        self._pending = None
+        return ev
 
     def run(self, stop=None) -> None:
         while self.step():
@@ -179,8 +183,7 @@ class AsyncStepper:
         if self._pending is None:
             return
         opt = self.optimizer
-        ev = self._pending.result()
-        self._pending = None
+        ev = self._result()
         opt.finish_step(ev, ingest=False)
         meta = opt.snapshot_meta()
         opt._ingest(ev)
@@ -303,11 +306,12 @@ class OptRunner:
                         opt.step()
                         self._after_generation(opt, opt.snapshot_meta(),
                                                history, generations, progress)
-                    dt = time.perf_counter() - t0
-                    _metrics.histogram("opt.generation_s").observe(dt)
-                    if dt > 0:
-                        _metrics.histogram("opt.evals_per_s").observe(
-                            (opt.evaluator.n_evals - n0) / dt)
+                    _metrics.histogram("opt.generation_s").observe(
+                        time.perf_counter() - t0)
+                    # sync: every evaluation dispatched this generation
+                    # was handed back to the optimizer inside it
+                    _metrics.counter("opt.evals_completed").inc(
+                        opt.evaluator.n_evals - n0)
                     if stop.requested():
                         break
             if stop.requested():

@@ -27,6 +27,7 @@ _CAP_FN_LOCK = threading.Lock()
 
 from ..core.design import Packaging, Technology
 from ..dse.sweep import DesignPoint
+from ..obs.trace import span as _span
 from ..topologies.grid import grid_dims
 
 # Parametric topologies valid for any chiplet count (hypercube needs powers
@@ -214,23 +215,38 @@ class AdjacencySpace(SearchSpace):
         connectivity pass replicates ``_repair_one``'s union-find root
         labeling with pointer-doubling gathers and merges every genome's
         components in lockstep. Bit-identical to mapping ``_repair_one`` over
-        the rows (asserted in tests/test_device_path.py)."""
-        bits = np.asarray(genomes, np.int64) % 2
-        P, G = bits.shape
-        if P == 0:
-            return bits
-        n, maxd = self.n_chiplets, self.max_degree
-        pu, pv = self.pair_u, self.pair_v
-        bits = bits.copy()
-        deg = self.degrees(bits)
+        the rows (asserted in tests/test_device_path.py). Spans:
+        ``space.repair`` with ``repair.degree_cap``, ``repair.reach`` and,
+        when rows are disconnected, ``repair.connect`` inside it."""
+        with _span("space.repair") as sp:
+            bits = np.asarray(genomes, np.int64) % 2
+            P = len(bits)
+            bad = np.zeros(0, np.int64)
+            if P:
+                with _span("repair.degree_cap"):
+                    deg = self._degree_cap(bits)
+                with _span("repair.reach"):
+                    bad = self._disconnected(bits)
+                if len(bad):
+                    with _span("repair.connect"):
+                        bits[bad] = self._connect_batch(bits[bad], deg[bad])
+            sp.set(genomes=P, connected=len(bad))
+        return bits
 
-        # 1. degree cap, dropping from the highest pair index down. Dropping
-        # only ever *decrements* degrees, so a vertex not over the cap at
-        # the start never goes over later. The scan is loop-carried (each
-        # drop changes the degrees later columns see), so it runs as a
-        # jitted lax.fori_loop over columns — integer ops, bit-identical to
-        # the Python scan, and off the optimizer's critical path even when
-        # crossover floods the population with over-cap children.
+    def _degree_cap(self, bits: np.ndarray) -> np.ndarray:
+        """Repair pass 1, in place on ``bits`` [P, G]: drop links from the
+        highest pair index down while an endpoint exceeds the cap. Returns
+        the capped degrees [P, n]."""
+        P, G = bits.shape
+        maxd = self.max_degree
+        pu, pv = self.pair_u, self.pair_v
+        deg = self.degrees(bits)
+        # Dropping only ever *decrements* degrees, so a vertex not over the
+        # cap at the start never goes over later. The scan is loop-carried
+        # (each drop changes the degrees later columns see), so it runs as
+        # a jitted lax.fori_loop over columns — integer ops, bit-identical
+        # to the Python scan, and off the optimizer's critical path even
+        # when crossover floods the population with over-cap children.
         over = deg > maxd
         if over.any():
             # Degrees only ever decrease, so the scan can touch exactly the
@@ -250,19 +266,22 @@ class AdjacencySpace(SearchSpace):
             b2, d2 = self._degree_cap_fn()(
                 bt, np.ascontiguousarray(deg.T, np.int32),
                 np.asarray(idx, np.int32))
-            bits = np.asarray(b2, np.int64)[:G].T.copy()
+            bits[:] = np.asarray(b2, np.int64)[:G].T
             deg = np.asarray(d2, np.int64).T.copy()
+        return deg
 
-        # 2. connectivity — only for genomes that need it. Connected ⟺
-        # every vertex reachable from vertex 0. The frontier expansion runs
-        # edge-wise through the incidence matrix — activate every set gene
-        # with a reached endpoint, scatter back to both endpoints via one
-        # sgemm — so the transient stays [P, G] (the genome's own footprint)
-        # instead of a dense [P, n, n] adjacency stack; already-connected
-        # genomes (the steady-state majority after variation) skip the
-        # union-find scan entirely.
+    def _disconnected(self, bits: np.ndarray) -> np.ndarray:
+        """Repair pass 2's test: rows of ``bits`` in which some vertex is
+        not reachable from vertex 0. The frontier expansion runs edge-wise
+        through the incidence matrix — activate every set gene with a
+        reached endpoint, scatter back to both endpoints via one sgemm — so
+        the transient stays [P, G] (the genome's own footprint) instead of
+        a dense [P, n, n] adjacency stack; already-connected genomes (the
+        steady-state majority after variation) skip the union-find scan
+        entirely."""
+        pu, pv = self.pair_u, self.pair_v
         bf = (bits == 1).astype(np.float32)
-        reach = np.zeros((P, n), np.float32)
+        reach = np.zeros((len(bits), self.n_chiplets), np.float32)
         reach[:, 0] = 1.0
         while True:
             active = bf * (reach[:, pu] + reach[:, pv])
@@ -270,10 +289,7 @@ class AdjacencySpace(SearchSpace):
             if np.array_equal(new, reach):
                 break
             reach = new
-        bad = np.nonzero(reach.min(axis=1) == 0)[0]
-        if len(bad):
-            bits[bad] = self._connect_batch(bits[bad], deg[bad])
-        return bits
+        return np.nonzero(reach.min(axis=1) == 0)[0]
 
     def _degree_cap_fn(self):
         """Jit-compiled descending degree-cap scan (built lazily, cached on

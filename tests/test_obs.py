@@ -401,6 +401,7 @@ def _synthetic_snapshot():
         "counters": [
             {"name": "opt.async.host_s", "labels": {}, "value": 3.0},
             {"name": "opt.async.wait_s", "labels": {}, "value": 1.0},
+            {"name": "opt.evals_completed", "labels": {}, "value": 240},
             {"name": "structure_cache.hit", "labels": {}, "value": 30},
             {"name": "structure_cache.miss", "labels": {}, "value": 10},
             {"name": "jit.compile",
@@ -429,13 +430,15 @@ def test_telemetry_derivation():
     disp = t["kernel_dispatch"]["apsp"]
     assert disp["backend=pallas,n=256,promoted=False,tile=128"] == 4
     assert t["generations"]["p99_s"] == 0.3
-    assert t["evals_per_s"] is None
+    # completed evaluations over the generations' summed time
+    assert t["evals_per_s"] == 240.0
 
 
 def test_telemetry_degrades_on_empty_snapshot():
     t = obs_report.telemetry({"counters": [], "gauges": [],
                               "histograms": []})
     assert t["async_overlap_pct"] is None
+    assert t["evals_per_s"] is None
     assert t["structure_cache"]["hit_rate"] is None
     assert t["jit_compiles"]["total"] == 0
     assert t["kernel_dispatch"] == {}
@@ -455,6 +458,7 @@ def test_summarize_and_format_report():
     assert gen["count"] == 2 and gen["total_s"] == 0.0015
     text = obs_report.format_report(summary)
     assert "async overlap:" in text and "75.0%" in text
+    assert "evals/s:              240 completed" in text
     assert "opt.generation" in text
 
 
