@@ -213,11 +213,13 @@ class AdjacencySpace(SearchSpace):
         """Vectorized over the whole population: the degree-cap pass is one
         descending scan over gene columns ([P] updates per column), the
         connectivity pass replicates ``_repair_one``'s union-find root
-        labeling with pointer-doubling gathers and merges every genome's
-        components in lockstep. Bit-identical to mapping ``_repair_one`` over
-        the rows (asserted in tests/test_device_path.py). Spans:
-        ``space.repair`` with ``repair.degree_cap``, ``repair.reach`` and,
-        when rows are disconnected, ``repair.connect`` inside it."""
+        labeling with pointer-doubling gathers, one step per link rank of
+        the disconnected rows, and merges every genome's components in
+        lockstep. Bit-identical to mapping ``_repair_one`` over the rows
+        (asserted in tests/test_device_path.py). Spans: ``space.repair``
+        (``genomes``, ``connected``) with ``repair.degree_cap``,
+        ``repair.reach`` and, when rows are disconnected, ``repair.connect``
+        (``rows`` repaired, union loop ``steps``) inside it."""
         with _span("space.repair") as sp:
             bits = np.asarray(genomes, np.int64) % 2
             P = len(bits)
@@ -228,8 +230,10 @@ class AdjacencySpace(SearchSpace):
                 with _span("repair.reach"):
                     bad = self._disconnected(bits)
                 if len(bad):
-                    with _span("repair.connect"):
-                        bits[bad] = self._connect_batch(bits[bad], deg[bad])
+                    with _span("repair.connect", rows=len(bad)) as cs:
+                        bits[bad], steps = self._connect_batch(bits[bad],
+                                                               deg[bad])
+                        cs.set(steps=steps)
             sp.set(genomes=P, connected=len(bad))
         return bits
 
@@ -337,27 +341,45 @@ class AdjacencySpace(SearchSpace):
             fn = self._cap_fn = cap
         return fn
 
-    def _connect_batch(self, bits: np.ndarray, deg: np.ndarray) -> np.ndarray:
+    def _connect_batch(self, bits: np.ndarray,
+                       deg: np.ndarray) -> tuple[np.ndarray, int]:
         """Connectivity repair for a (sub)population of degree-capped
-        genomes, replicating the union-find root labels of ``_repair_one``:
-        surviving genes are processed in ascending order, and the invariant
-        "parent is fully path-compressed before each union" makes one
-        pointer-doubling gather per gene sufficient. Components are then
-        unioned in lockstep, each genome joining its two lowest-rooted
-        components at their minimum-degree (lowest-index) chiplets — the
-        same deterministic rule as the sequential pass."""
+        genomes, replicating the union-find root labels of ``_repair_one``.
+        Returns the repaired genomes and the union loop's step count.
+
+        The union loop runs over link *rank*: step k applies the k-th set
+        gene (ascending) of every row at once, so it runs as many steps as
+        the row with the most links has, not one per gene column set in any
+        row. Rows with fewer links are padded with the pair (0, 0), whose
+        endpoints share a root, so the union is a no-op. Each row still
+        sees its own genes in ascending order, and the invariant "parent is
+        fully path-compressed before each union" makes one pointer-doubling
+        gather per step sufficient; ``parent[ru] = rv`` then yields the
+        sequential pass's root labels exactly. ``parent`` is kept flat,
+        row r's chiplet x at r * n + x, so a doubling is one 1-D gather.
+        Components are then unioned in lockstep, each genome joining its
+        two lowest-rooted components at their minimum-degree (lowest-index)
+        chiplets — the same deterministic rule as the sequential pass."""
         P, _ = bits.shape
         n = self.n_chiplets
-        pu, pv = self.pair_u, self.pair_v
         rows = np.arange(P)
-        parent = np.tile(np.arange(n), (P, 1))
-        for g in np.nonzero(bits.any(axis=0))[0]:
-            parent = parent[rows[:, None], parent]
-            ru = parent[rows, pu[g]]
-            rv = parent[rows, pv[g]]
-            m = (bits[:, g] == 1) & (ru != rv)
-            parent[rows[m], ru[m]] = rv[m]
-        roots = parent[rows[:, None], parent]
+        r, g = np.nonzero(bits)            # row-major: genes ascend per row
+        counts = np.bincount(r, minlength=P)
+        steps = int(counts.max()) if len(r) else 0
+        rank = np.arange(len(r)) - (np.cumsum(counts) - counts)[r]
+        base = rows * n
+        ends_u = np.tile(base, (steps, 1))     # [steps, P], padded (0, 0)
+        ends_v = ends_u.copy()
+        ends_u[rank, r] += self.pair_u[g]
+        ends_v[rank, r] += self.pair_v[g]
+        parent = np.arange(P * n)
+        for k in range(steps):
+            parent = parent[parent]
+            ru = parent[ends_u[k]]
+            rv = parent[ends_v[k]]
+            m = ru != rv
+            parent[ru[m]] = rv[m]
+        roots = parent[parent].reshape(P, n) - base[:, None]
 
         score_idx = np.arange(n)[None, :]
         big = np.int64(n * n + n)
@@ -383,7 +405,7 @@ class AdjacencySpace(SearchSpace):
             deg[t, v[todo]] += 1
             roots = np.where(todo[:, None] & (roots == second[:, None]),
                              first[:, None], roots)
-        return bits
+        return bits, steps
 
     def _repair_one(self, bits: np.ndarray) -> np.ndarray:
         """Sequential single-genome reference for ``repair`` (the oracle the

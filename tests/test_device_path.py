@@ -239,6 +239,62 @@ def test_repair_handles_empty_and_full_genomes():
         assert np.array_equal(got, want)
 
 
+def _mixed_population(space: AdjacencySpace, seed: int) -> np.ndarray:
+    """16 rows whose link counts differ widely: empty, full, near-tree
+    (a random spanning tree less a few links), dense rows the degree cap
+    trims, and sparse random rows that are mostly disconnected."""
+    n, G = space.n_chiplets, space.genome_length
+    rng = np.random.default_rng(seed)
+    rows = [np.zeros(G, np.int64), np.ones(G, np.int64)]
+    for cut in (0, 1, 3):
+        perm = rng.permutation(n)
+        tree = np.zeros(G, np.int64)
+        for i in range(1, n):
+            a, b = sorted((perm[i], perm[rng.integers(0, i)]))
+            tree[space._pair_index(a, b)] = 1
+        tree[rng.choice(np.nonzero(tree)[0], cut, replace=False)] = 0
+        rows.append(tree)
+    for density in (0.5, 0.2, space.init_density):
+        rows.append((rng.random(G) < density).astype(np.int64))
+    while len(rows) < 16:
+        density = rng.uniform(0.1, 1.5) / n
+        rows.append((rng.random(G) < density).astype(np.int64))
+    return np.stack(rows)[rng.permutation(16)]
+
+
+@pytest.mark.parametrize("n,seed", [(64, 0), (64, 1), (128, 2), (128, 3)])
+def test_repair_bit_identical_at_larger_n_with_uneven_link_counts(n, seed):
+    """Rows with very different link counts exercise the rank-ordered
+    union loop's padding; the output must equal the sequential oracle."""
+    space = AdjacencySpace(n_chiplets=n, max_degree=8)
+    raw = _mixed_population(space, seed)
+    got = space.repair(raw)
+    want = np.stack([space._repair_one(g.copy()) for g in raw])
+    assert np.array_equal(got, want)
+
+
+@pytest.mark.parametrize("n", [64, 128])
+def test_repair_connect_span_counts_rows_and_steps(n):
+    from repro.obs.trace import TRACER, disable_tracing, enable_tracing
+
+    space = AdjacencySpace(n_chiplets=n, max_degree=8)
+    raw = _mixed_population(space, n)
+    capped = raw.copy()
+    space._degree_cap(capped)
+    bad = [b for b in capped if not _connected(space, b)]
+    assert bad
+    enable_tracing()
+    try:
+        space.repair(raw)
+    finally:
+        disable_tracing()
+    spans = [s for s in TRACER.to_dicts() if s["name"] == "repair.connect"]
+    assert len(spans) == 1
+    attrs = spans[0]["attrs"]
+    assert attrs["rows"] == len(bad)
+    assert 0 < attrs["steps"] <= max(int(b.sum()) for b in bad)
+
+
 def _connected(space: AdjacencySpace, bits: np.ndarray) -> bool:
     n = space.n_chiplets
     adj = np.zeros((n, n), bool)
