@@ -64,6 +64,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core.latency import num_doubling_steps
 from ..core.reports import ReportArrays
+from ..faults.objectives import REACH_EPS
 from ..kernels.ops import load_propagate
 from ..kernels.ref import BIG
 from ..obs import metrics as _metrics
@@ -765,7 +766,7 @@ class AdjacencyPipeline:
             raise ValueError(
                 f"node_fail shape {node_fail.shape} != ({F}, {self.n})")
         with _span("genomes.dispatch_faults", space="adjacency", pop=Pn,
-                   n=self.n, faults=F):
+                   n=self.n, faults=F) as sp:
             deg = self.space.degrees(genomes)
             if deg.max(initial=0) > self.k_phys:
                 raise ValueError(
@@ -774,6 +775,7 @@ class AdjacencyPipeline:
                     f"before evaluate_genomes")
             ndev = int(np.prod(list(self.mesh.shape.values())))
             bp = bucket_population(Pn, ndev)
+            sp.set(rows=bp * F)      # grid rows the device program runs
             padded = genomes
             if bp != Pn:
                 padded = np.concatenate(
@@ -787,23 +789,24 @@ class AdjacencyPipeline:
             fn = _adjacency_faults_fn(self.mesh, self.n, self.k_phys,
                                       self._euclid, self.max_hops,
                                       _donate_ok())
-            lat, thr, reach, len_sum = fn(
-                bits, link_alive, node_alive, self._pair_u, self._pair_v,
-                self._pair_id, self._chain_slot, self._chain_eslot,
-                self._inv_j, self._inv_c, self._col, self._row,
-                self._side, self._phyx, self._phyy, self._cphyx,
-                self._cphyy, self._bw, self._traffic, self._consts)
+            lat, thr, reach, len_sum = fn(bits, link_alive, node_alive,
+                                          *self._tables())
 
         def finish() -> FaultGridResult:
             with _span("genomes.finish_faults", space="adjacency", pop=Pn):
                 pending.block()
-                reports = self._report_arrays(genomes, deg,
-                                              np.asarray(len_sum)[:Pn])
-                return FaultGridResult(
-                    latency=np.asarray(lat)[:Pn],
-                    throughput=np.asarray(thr)[:Pn],
-                    reachable_fraction=np.asarray(reach)[:Pn],
-                    reports=reports)
+                with _span("genomes.reports"):
+                    reports = self._report_arrays(genomes, deg,
+                                                  np.asarray(len_sum)[:Pn])
+                    res = FaultGridResult(
+                        latency=np.asarray(lat)[:Pn],
+                        throughput=np.asarray(thr)[:Pn],
+                        reachable_fraction=np.asarray(reach)[:Pn],
+                        reports=reports)
+                _metrics.counter("faults.rows").inc(Pn * F)
+                _metrics.counter("faults.disconnected_rows").inc(int(
+                    (res.reachable_fraction < 1.0 - REACH_EPS).sum()))
+                return res
 
         pending = PendingGenomeEval(finish, (lat, thr, reach, len_sum))
         return pending
@@ -813,6 +816,40 @@ class AdjacencyPipeline:
         """Blocking wrapper over ``evaluate_faults_async``."""
         return self.evaluate_faults_async(genomes, link_fail,
                                           node_fail).result()
+
+    def faults_memory(self, pop: int, n_scenarios: int) -> dict[str, int]:
+        """Bytes per device of the fault-grid program that
+        ``evaluate_faults_async`` runs for ``pop`` genomes under
+        ``n_scenarios`` scenarios, from the compiled executable's own
+        accounting: arguments, outputs, temporaries and generated code.
+        Compiles (or finds in the compilation caches) the same program;
+        runs nothing."""
+        ndev = int(np.prod(list(self.mesh.shape.values())))
+        rep = NamedSharding(self.mesh, P())
+        fn = _adjacency_faults_fn(self.mesh, self.n, self.k_phys,
+                                  self._euclid, self.max_hops, _donate_ok())
+        G = self.space.genome_length
+        mem = fn.lower(
+            jax.ShapeDtypeStruct((bucket_population(pop, ndev), G),
+                                 jnp.int32,
+                                 sharding=NamedSharding(self.mesh,
+                                                        P("data"))),
+            jax.ShapeDtypeStruct((n_scenarios, G), jnp.bool_, sharding=rep),
+            jax.ShapeDtypeStruct((n_scenarios, self.n), jnp.bool_,
+                                 sharding=rep),
+            *self._tables()).compile().memory_analysis()
+        return {"argument_bytes": int(mem.argument_size_in_bytes),
+                "output_bytes": int(mem.output_size_in_bytes),
+                "temp_bytes": int(mem.temp_size_in_bytes),
+                "code_bytes": int(mem.generated_code_size_in_bytes)}
+
+    def _tables(self) -> tuple:
+        """The replicated geometry, PHY, bandwidth, traffic and constant
+        tables every fault-grid call takes after its three inputs."""
+        return (self._pair_u, self._pair_v, self._pair_id, self._chain_slot,
+                self._chain_eslot, self._inv_j, self._inv_c, self._col,
+                self._row, self._side, self._phyx, self._phyy, self._cphyx,
+                self._cphyy, self._bw, self._traffic, self._consts)
 
     def _report_arrays(self, genomes, deg, len_sums) -> ReportArrays:
         """Constraint columns [P] in host float64, exact against

@@ -6,8 +6,9 @@
   exactly from the trace events;
 * ``telemetry`` — the derived health numbers the benchmarks and CI gates
   consume: async overlap %, structure-cache hit rate, jit compile counts,
-  per-backend kernel dispatch counts, completed evals/s and p99 step
-  latency. This is the ``telemetry`` block committed into BENCH_opt.json.
+  per-backend kernel dispatch counts, completed evals/s, p99 step
+  latency and the fault grid's row counts. This is the ``telemetry``
+  block committed into BENCH_opt.json.
 
 ``format_report`` renders the human table; ``dump_run`` exports everything
 a finished run has to say (JSONL trace, Chrome/Perfetto trace, metrics
@@ -155,6 +156,16 @@ def telemetry(snapshot: dict) -> dict:
                 if gen_s and gen_s["sum"] > 0 and completed else None)
     ingest_s = _histogram(snapshot, "opt.ingest_s")
 
+    # -- fault grid: scenario rows evaluated, rows that disconnected
+    # traffic, designs the disconnection constraint rejected
+    fault_rows = _counter_value(snapshot, "faults.rows")
+    faults = ({"rows": int(fault_rows),
+               "disconnected_rows": int(_counter_value(
+                   snapshot, "faults.disconnected_rows")),
+               "infeasible": int(_counter_value(snapshot,
+                                                "faults.infeasible"))}
+              if fault_rows else None)
+
     return {
         "async_overlap_pct": (round(overlap, 2)
                               if overlap is not None else None),
@@ -174,6 +185,7 @@ def telemetry(snapshot: dict) -> dict:
                          "p99_s": ingest_s["p99"],
                          "total_s": round(ingest_s["sum"], 4)}
                         if ingest_s else None),
+        "faults": faults,
     }
 
 
@@ -237,6 +249,11 @@ def format_report(summary: dict) -> str:
     if t["evals_per_s"]:
         lines.append(f"evals/s:              {t['evals_per_s']:.4g} "
                      f"completed evaluations over the generations' time")
+    if t["faults"]:
+        f = t["faults"]
+        lines.append(f"fault grid:           {f['rows']} scenario rows, "
+                     f"{f['disconnected_rows']} disconnected traffic; "
+                     f"{f['infeasible']} designs infeasible")
     lines += ["", "-- spans --"]
     header = ("span", "count", "total_s", "p50_s", "p99_s", "threads")
     rows = [header]

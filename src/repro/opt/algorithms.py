@@ -249,9 +249,12 @@ class PopulationEvaluator:
         from ..faults.objectives import reduce_grid, robust_columns
         with _span("opt.finalize", evals=len(genomes), path="faults"):
             sc = self.faults.scenarios
-            reduced = reduce_grid(grid.latency, grid.throughput,
-                                  grid.reachable_fraction, sc.weights)
-            lat, thr, ok = robust_columns(reduced, self.faults.objectives)
+            with _span("faults.reduce", rows=grid.latency.size):
+                reduced = reduce_grid(grid.latency, grid.throughput,
+                                      grid.reachable_fraction, sc.weights)
+                lat, thr, ok = robust_columns(reduced,
+                                              self.faults.objectives)
+            _metrics.counter("faults.infeasible").inc(int((~ok).sum()))
             try:
                 pristine = sc.names.index("pristine")
             except ValueError:

@@ -96,6 +96,24 @@ def test_pristine_scenario_reproduces_unfaulted_eval(pipe8, genomes8):
                                   np.ones(len(genomes8), np.float32))
 
 
+def test_faults_memory_accounts_for_the_grid_program(pipe8, genomes8):
+    """The compiled grid program's own accounting: its arguments hold the
+    bucketed population's bits and the scenario masks, its outputs the
+    [P, F] grid's three columns plus the length sums; the pipeline
+    evaluates as before afterwards."""
+    sc = single_link_faults(pipe8.space, top_k=5)
+    F, G = sc.n_scenarios, pipe8.space.genome_length
+    mem = pipe8.faults_memory(len(genomes8), F)
+    assert set(mem) == {"argument_bytes", "output_bytes", "temp_bytes",
+                        "code_bytes"}
+    bp = 8                                   # bucket of a population of 3
+    assert mem["argument_bytes"] >= 4 * bp * G + F * G + F * pipe8.n
+    assert mem["output_bytes"] >= 4 * (3 * bp * F + bp)
+    assert mem["temp_bytes"] >= 0
+    grid = pipe8.evaluate_faults(genomes8, sc.link_fail, sc.node_fail)
+    assert grid.latency.shape == (len(genomes8), F)
+
+
 def test_faulting_all_links_disconnects_everything(pipe8, genomes8):
     space = pipe8.space
     link_fail = np.ones((1, space.genome_length), bool)
@@ -353,6 +371,44 @@ def test_fault_resume_reproduces_uninterrupted_run(tmp_path):
          for e in r_res.archive.front()]
     assert a == b
     assert r_full.n_evals == r_res.n_evals
+
+
+def test_fault_async_stepper_matches_sync_runner():
+    """The path the benchmark's fault cell runs: ``AsyncStepper`` over a
+    ``PopulationEvaluator`` with a ``FaultSetup`` (n=16, P=8, F=9) gives
+    the synchronous ``OptRunner``'s archive front, evaluation count,
+    robust columns of every generation and final optimizer state."""
+    from repro.opt import AsyncStepper
+
+    gens = 4
+    runs = {}
+    for mode in ("sync", "async"):
+        space = AdjacencySpace(n_chiplets=16, max_degree=8)
+        sc = single_link_faults(space, top_k=8)
+        assert sc.n_scenarios == 9
+        ev = PopulationEvaluator(space, faults=FaultSetup(scenarios=sc))
+        columns = []
+        finalize = ev._finalize_faults
+
+        def keep(genomes, grid, _finalize=finalize, _columns=columns):
+            out = _finalize(genomes, grid)
+            _columns.append({k: v.tolist() for k, v in out.extra.items()})
+            return out
+
+        ev._finalize_faults = keep
+        opt = EvolutionarySearch(space, ev, seed=4, pop_size=8)
+        if mode == "async":
+            AsyncStepper(opt, gens).run()
+        else:
+            OptRunner(opt).run(gens)
+        runs[mode] = opt, columns
+    (s_opt, s_cols), (a_opt, a_cols) = runs["sync"], runs["async"]
+    assert s_opt.evaluator.n_evals == a_opt.evaluator.n_evals == gens * 8
+    assert len(s_cols) == gens and s_cols == a_cols
+    assert s_opt.state() == a_opt.state()
+    front = lambda o: [(e.latency, e.throughput, e.payload, e.metrics)
+                       for e in o.archive.front()]
+    assert front(s_opt) and front(s_opt) == front(a_opt)
 
 
 def test_faults_require_device_path():

@@ -410,6 +410,9 @@ def _synthetic_snapshot():
             {"name": "ops.apsp.dispatch",
              "labels": {"backend": "pallas", "tile": 128,
                         "promoted": False, "n": 256}, "value": 4},
+            {"name": "faults.rows", "labels": {}, "value": 330},
+            {"name": "faults.disconnected_rows", "labels": {}, "value": 12},
+            {"name": "faults.infeasible", "labels": {}, "value": 7},
         ],
         "gauges": [],
         "histograms": [
@@ -432,6 +435,8 @@ def test_telemetry_derivation():
     assert t["generations"]["p99_s"] == 0.3
     # completed evaluations over the generations' summed time
     assert t["evals_per_s"] == 240.0
+    assert t["faults"] == {"rows": 330, "disconnected_rows": 12,
+                           "infeasible": 7}
 
 
 def test_telemetry_degrades_on_empty_snapshot():
@@ -442,6 +447,7 @@ def test_telemetry_degrades_on_empty_snapshot():
     assert t["structure_cache"]["hit_rate"] is None
     assert t["jit_compiles"]["total"] == 0
     assert t["kernel_dispatch"] == {}
+    assert t["faults"] is None
 
 
 def test_summarize_and_format_report():
@@ -459,6 +465,7 @@ def test_summarize_and_format_report():
     text = obs_report.format_report(summary)
     assert "async overlap:" in text and "75.0%" in text
     assert "evals/s:              240 completed" in text
+    assert "fault grid:           330 scenario rows, 12 disconnected" in text
     assert "opt.generation" in text
 
 

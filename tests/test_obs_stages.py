@@ -236,3 +236,62 @@ def test_block_span_in_the_faults_and_parametric_finishers(engine):
         blk = [b for b in _named(spans, "genomes.block") if _inside(b, fin)]
         assert len(blk) == 1
         assert pending.block_s >= blk[0]["dur_us"] / 1e6
+
+
+def test_fault_run_names_its_reduction_reports_and_rows(engine):
+    """A traced fault-aware ``AsyncStepper`` run: ``faults.reduce`` inside
+    each generation's finalize, ``genomes.reports`` after the block inside
+    ``genomes.finish_faults``, the dispatch span's padded ``rows``, and the
+    counters ``faults.rows`` (P * F per generation, unpadded),
+    ``faults.disconnected_rows`` and ``faults.infeasible``, which the
+    telemetry prints."""
+    from repro.faults.model import single_link_faults
+    from repro.faults.objectives import REACH_EPS, FaultSetup
+    from repro.obs import report as obs_report
+
+    space = AdjacencySpace(n_chiplets=N)
+    sc = single_link_faults(space, top_k=8)
+    F, pop, gens = sc.n_scenarios, 6, 3             # P pads to 8
+    ev = PopulationEvaluator(space, engine=engine,
+                             faults=FaultSetup(scenarios=sc))
+    want = {"faults.rows": 0, "faults.disconnected_rows": 0,
+            "faults.infeasible": 0}
+    finalize = ev._finalize_faults
+
+    def keep(genomes, grid):
+        out = finalize(genomes, grid)
+        want["faults.rows"] += grid.latency.size
+        want["faults.disconnected_rows"] += int(
+            (grid.reachable_fraction < 1.0 - REACH_EPS).sum())
+        want["faults.infeasible"] += int(
+            (out.extra["disconnect_prob"] > 1e-12).sum())
+        return out
+
+    ev._finalize_faults = keep
+    opt = EvolutionarySearch(space, ev, seed=3, pop_size=pop)
+    before = {c: _counter(c) for c in want}
+    enable_tracing()
+    try:
+        AsyncStepper(opt, gens).run()
+    finally:
+        disable_tracing()
+    spans = TRACER.to_dicts()
+    assert want["faults.rows"] == gens * pop * F
+    assert {c: _counter(c) - v for c, v in before.items()} == want
+    fins = _named(spans, "genomes.finish_faults")
+    assert len(fins) == gens
+    for fin in fins:
+        blk, = [b for b in _named(spans, "genomes.block") if _inside(b, fin)]
+        rep, = [r for r in _named(spans, "genomes.reports")
+                if _inside(r, fin)]
+        assert _end(blk) <= rep["ts_us"] + EPS_US
+    finals = [s for s in _named(spans, "opt.finalize")
+              if s["attrs"]["path"] == "faults"]
+    reduces = _named(spans, "faults.reduce")
+    assert len(reduces) == len(finals) == gens
+    assert all(_within_any(r, finals) for r in reduces)
+    assert all(r["attrs"]["rows"] == pop * F for r in reduces)
+    dispatches = _named(spans, "genomes.dispatch_faults")
+    assert [d["attrs"]["rows"] for d in dispatches] == [8 * F] * gens
+    tele = obs_report.telemetry(obs_metrics.REGISTRY.snapshot())
+    assert tele["faults"]["rows"] >= want["faults.rows"]

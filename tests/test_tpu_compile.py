@@ -122,3 +122,33 @@ def test_adjacency_program_compiles(topo, monkeypatch, n, pop):
     compiled = fn.lower(bits, *(_spec(t.shape, t.dtype, rep)
                                 for t in tables)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_fault_grid_program_compiles_and_fits_one_chip(topo, monkeypatch):
+    """The fused [P, F] population x fault grid at the benchmark's fault
+    cell size (n=64, P=256, F=33: 8,448 degraded designs in one program)
+    compiles for one described v5e, and its buffers fit the chip's 16 GB
+    with room for the rest of the process."""
+    from repro.dse.genomes import AdjacencyPipeline, _adjacency_faults_fn
+    from repro.opt.space import AdjacencySpace
+    from repro.utils.jaxcompat import make_auto_mesh
+
+    monkeypatch.setenv("REPRO_LOAD_PROP_BACKEND", "pallas")
+    n, pop, F = 64, 256, 33
+    space = AdjacencySpace(n_chiplets=n, max_degree=8)
+    pipe = AdjacencyPipeline(space, make_auto_mesh(
+        (1,), ("data",), devices=jax.devices()[:1]))
+    tables = pipe._tables()
+    mesh = jax.sharding.Mesh(np.asarray(topo.devices[:1]), ("data",))
+    rep = NamedSharding(mesh, P())
+    G = space.genome_length
+    fn = _adjacency_faults_fn(mesh, n, pipe.k_phys, pipe._euclid,
+                              pipe.max_hops, False)
+    compiled = fn.lower(
+        _spec((pop, G), jnp.int32, NamedSharding(mesh, P("data"))),
+        _spec((F, G), jnp.bool_, rep), _spec((F, n), jnp.bool_, rep),
+        *(_spec(t.shape, t.dtype, rep) for t in tables)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+            + mem.output_size_in_bytes) < 8e9
