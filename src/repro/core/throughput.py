@@ -20,8 +20,9 @@ The Pallas kernel in ``kernels/flow_accum.py`` builds the masks on the fly
 from iota comparisons inside VMEM (nothing materialized in HBM); the jnp
 fallback here uses segment-sum scatter, which XLA handles fine on CPU.
 
-The number of hop steps is the network diameter — a static bound passed in
-(defaults to n-1, the worst case; topology generators provide tight bounds).
+The number of hop steps is at most a static bound passed in (defaults to
+n-1, the worst case; topology generators provide tight bounds); with
+``adaptive`` the loop stops at the routed diameter.
 """
 from __future__ import annotations
 
@@ -63,11 +64,13 @@ def edge_flows(next_hop: jax.Array, traffic: jax.Array,
     alternative TPU story for very large n, kept as an independent
     implementation (and test oracle).
 
-    ``adaptive=True`` replaces the fixed-length scan with a while_loop that
-    stops once every route has reached its destination (``max_hops`` stays
-    the safety bound). Same flows; the trip count becomes the actual routed
-    diameter instead of the static bound. Under vmap the loop runs until
-    the *batch* maximum diameter. Unreachable pairs (next_hop self-loops)
+    ``adaptive=True`` replaces the fixed-length loop with one that stops
+    once every route has reached its destination (``max_hops`` stays the
+    safety bound), on either path: the primitive passes it to every
+    backend, the fused kernels included. Same flows; the trip count
+    becomes the actual routed diameter instead of the static bound. Under
+    vmap an XLA loop runs until the *batch* maximum diameter, the kernel
+    until each design's own. Unreachable pairs (next_hop self-loops)
     never deliver, so both variants accumulate them on the diagonal for
     exactly ``max_hops`` hops (zero-bandwidth self-edges then drive the
     proxy to 0).
@@ -105,7 +108,8 @@ def edge_flows(next_hop: jax.Array, traffic: jax.Array,
         return jnp.any((state[0] != dest) & (amount > 0))
 
     flow0 = jnp.zeros((n, n), dtype=jnp.float32)
-    _, flow = hop_loop(step, (cur0, flow0), max_hops, adaptive, still_active)
+    _, (_, flow) = hop_loop(step, (cur0, flow0), max_hops, adaptive,
+                            still_active)
     return flow
 
 

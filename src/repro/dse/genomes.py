@@ -28,13 +28,16 @@ These pipelines remove the host from the loop:
 Both pipelines shard the population axis across every device of the engine
 mesh via ``shard_map`` (ISSUE 5): the fused program runs per shard with all
 lookup tables replicated and zero cross-device communication, so the same
-code spans 1 CPU device or a full accelerator mesh, and per-shard adaptive
-loops stop at each shard's routed diameter. The proxies' hot loop
+code spans 1 CPU device or a full accelerator mesh. The proxies' hot loop
 dispatches through the shared ``kernels.ops.load_propagate`` primitive
-(fused Pallas kernel on TPU, adaptive XLA loop elsewhere;
-``REPRO_LOAD_PROP_BACKEND`` overrides). ``evaluate_async`` dispatches
-without blocking — the async optimizer driver (``opt.runner.AsyncStepper``)
-overlaps archive/checkpoint work with the in-flight call.
+(fused Pallas kernel on TPU, XLA loop elsewhere;
+``REPRO_LOAD_PROP_BACKEND`` overrides), whose hop loop stops at each
+design's routed diameter in the kernel and at each shard's in the XLA
+loop; ``AdjacencyPipeline``'s finishers count the hops it ran
+(``kernels.load_propagate.hops`` over ``kernels.load_propagate.designs``).
+``evaluate_async`` dispatches without blocking — the async optimizer
+driver (``opt.runner.AsyncStepper``) overlaps archive/checkpoint work with
+the in-flight call.
 
 Both pipelines are jit-cache-stable: the population axis is padded to
 power-of-two buckets (×device-count multiples), ``ParametricPipeline``
@@ -65,7 +68,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ..core.latency import num_doubling_steps
 from ..core.reports import ReportArrays
 from ..faults.objectives import REACH_EPS
-from ..kernels.ops import load_propagate
+from ..kernels.ops import load_propagate_counted
 from ..kernels.ref import BIG
 from ..obs import metrics as _metrics
 from ..obs.trace import span as _span
@@ -201,8 +204,8 @@ class FaultGridResult:
 def _eval_proxies(next_hop, step_cost, node_weight, adj_bw, traffic,
                   max_hops: int):
     """Both proxies from ONE load-propagation pass through the shared
-    primitive ``kernels.ops.load_propagate`` (Pallas-fused on TPU, adaptive
-    XLA loop elsewhere): the accumulated per-destination load W[d, u] gives
+    primitive ``kernels.ops.load_propagate`` (Pallas-fused on TPU, XLA loop
+    elsewhere): the accumulated per-destination load W[d, u] gives
     the edge flows via the primitive's final contraction, and — because a
     unit of traffic pays step_cost(u, nh[u, d]) each time it leaves u — the
     traffic-weighted total path cost is
@@ -211,18 +214,18 @@ def _eval_proxies(next_hop, step_cost, node_weight, adj_bw, traffic,
 
     which replaces the whole path-doubling pass. Exact for connected
     (repaired) designs, where every routed pair terminates; ``max_hops`` is
-    the shape-stable safety bound (n-1), the adaptive loop stops at the
-    batch's actual routed diameter (per *shard* under ``shard_map``).
+    the safety bound (n-1), and the adaptive loop stops at each design's
+    routed diameter in the kernels (the XLA loop: the shard's largest).
     Matches the reference proxies to f32 summation order (asserted against
-    the host path in tests).
+    the host path in tests). Returns (latency, throughput, hops run) [P].
     """
     Pn, n, _ = next_hop.shape
     t32 = traffic.astype(jnp.float32)
     t_total = jnp.sum(t32)
     dest_weight = jnp.sum(jnp.sum(t32, axis=0) * node_weight)
     load0 = jnp.broadcast_to(t32.T[None], (Pn, n, n))
-    total, flow = load_propagate(next_hop, load0, max_hops=max_hops,
-                                 adaptive=True)
+    total, flow, hops = load_propagate_counted(
+        next_hop, load0, max_hops=max_hops, adaptive=True)
     f = flow + flow.swapaxes(-1, -2)
     ratio = jnp.where(f > 0, adj_bw / jnp.maximum(f, 1e-30), jnp.inf)
     thr = (jnp.min(ratio, axis=(1, 2)) * t_total).astype(jnp.float32)
@@ -231,7 +234,7 @@ def _eval_proxies(next_hop, step_cost, node_weight, adj_bw, traffic,
                                   axis=2)                        # [P, u, d]
     lat = ((jnp.sum(total * sc_next.swapaxes(-1, -2), axis=(1, 2))
             + dest_weight) / t_total).astype(jnp.float32)
-    return lat, thr
+    return lat, thr, hops
 
 
 def _adjacency_structure(bits, pair_u, pair_v, pair_id, chain_slot,
@@ -408,7 +411,8 @@ def _adjacency_eval(bits, pair_u, pair_v, pair_id, chain_slot, chain_eslot,
     replicated), so the whole pipeline scales across ``jax.devices()`` with
     zero cross-device communication. The decode/geometry half lives in
     ``_adjacency_structure`` (shared with the fault grid); this adds the
-    batched routing tables and the two proxies."""
+    batched routing tables and the two proxies. Returns (latency,
+    throughput, summed link length, load-propagation hops), each [P]."""
     Pn, G = bits.shape
     _note_compile(("adjacency", Pn, G, n, k_phys, max_hops))
     internal = consts[4]
@@ -422,10 +426,10 @@ def _adjacency_eval(bits, pair_u, pair_v, pair_id, chain_slot, chain_eslot,
 
     # --- proxies ---
     node_weight = jnp.full((n,), internal, jnp.float32)
-    lat_m, thr_m = _eval_proxies(next_hop, step_cost, node_weight, adj_bw,
-                                 traffic, max_hops)
+    lat_m, thr_m, hops = _eval_proxies(next_hop, step_cost, node_weight,
+                                       adj_bw, traffic, max_hops)
     len_sum = jnp.sum(jnp.where(bits.astype(bool), length, 0.0), axis=1)
-    return lat_m, thr_m, len_sum
+    return lat_m, thr_m, len_sum, hops
 
 
 @_locked_factory
@@ -440,7 +444,7 @@ def _adjacency_eval_fn(mesh, n: int, k_phys: int, euclid: bool,
     impl = functools.partial(_adjacency_eval, n=n, k_phys=k_phys,
                              euclid=euclid, max_hops=max_hops)
     f = shard_map(impl, mesh=mesh, in_specs=(P("data"),) + (P(),) * 17,
-                  out_specs=(P("data"),) * 3, check_vma=False)
+                  out_specs=(P("data"),) * 4, check_vma=False)
     return jax.jit(f, donate_argnums=(0,) if donate else ())
 
 
@@ -465,8 +469,11 @@ def _eval_proxies_masked(next_hop, step_cost, node_weight, adj_bw, traffic,
     [B, n] node-alive mask; traffic: [n, n] shared. Returns (latency,
     throughput, reachable_fraction) each [B] f32 — latency/throughput of
     the *delivered* traffic (BIG / 0.0 when nothing routes), and the
-    delivered fraction of total offered traffic. Reduces exactly to the
-    pristine proxies when every node is alive and the graph is connected.
+    delivered fraction of total offered traffic — and the hops the load
+    propagation ran [B] int32 (undelivered pairs carry no load, so a
+    degraded design stops at its reachable diameter). Reduces exactly to
+    the pristine proxies when every node is alive and the graph is
+    connected.
     """
     B, n, _ = next_hop.shape
     t32 = traffic.astype(jnp.float32)
@@ -478,8 +485,8 @@ def _eval_proxies_masked(next_hop, step_cost, node_weight, adj_bw, traffic,
     t_m = t32[None] * deliver                        # [B, n, n] src-major
     t_tot = jnp.sum(t_m, axis=(1, 2))                # [B]
     dest_weight = jnp.sum(t_m * node_weight[None, None, :], axis=(1, 2))
-    total, flow = load_propagate(next_hop, t_m.swapaxes(-1, -2),
-                                 max_hops=max_hops, adaptive=True)
+    total, flow, hops = load_propagate_counted(
+        next_hop, t_m.swapaxes(-1, -2), max_hops=max_hops, adaptive=True)
     f = flow + flow.swapaxes(-1, -2)
     ratio = jnp.where(f > 0, adj_bw / jnp.maximum(f, 1e-30), jnp.inf)
     min_ratio = jnp.min(ratio, axis=(1, 2))
@@ -493,7 +500,7 @@ def _eval_proxies_masked(next_hop, step_cost, node_weight, adj_bw, traffic,
     thr = jnp.where(routed, min_ratio * t_tot, 0.0).astype(jnp.float32)
     reach_frac = (t_tot / jnp.maximum(jnp.sum(t32), 1e-30)
                   ).astype(jnp.float32)
-    return lat, thr, reach_frac
+    return lat, thr, reach_frac, hops
 
 
 def _adjacency_eval_faults(bits, link_alive, node_alive, pair_u, pair_v,
@@ -516,8 +523,8 @@ def _adjacency_eval_faults(bits, link_alive, node_alive, pair_u, pair_v,
     the grid is materialized as [P*F, n, n] gathers (static iota row/
     scenario indices), never as a [P, F, n, n] transient (audited in
     ``analysis.registry``). Returns (latency, throughput,
-    reachable_fraction) each [P, F] f32 plus the pristine summed link
-    length [P]."""
+    reachable_fraction) each [P, F] f32, the pristine summed link
+    length [P] and the load-propagation hops [P, F] int32."""
     Pn, G = bits.shape
     F = link_alive.shape[0]
     _note_compile(("adjacency_faults", Pn, F, G, n, k_phys, max_hops))
@@ -546,12 +553,12 @@ def _adjacency_eval_faults(bits, link_alive, node_alive, pair_u, pair_v,
 
     next_hop = hops_next_hop_batch(adj_pf)
     node_weight = jnp.full((n,), internal, jnp.float32)
-    lat, thr, reach = _eval_proxies_masked(
+    lat, thr, reach, hops = _eval_proxies_masked(
         next_hop, step_pf, node_weight, bw_pf, traffic, node_ok[f_idx],
         max_hops)
     len_sum = jnp.sum(jnp.where(bits.astype(bool), length, 0.0), axis=1)
     return (lat.reshape(Pn, F), thr.reshape(Pn, F), reach.reshape(Pn, F),
-            len_sum)
+            len_sum, hops.reshape(Pn, F))
 
 
 @_locked_factory
@@ -567,7 +574,7 @@ def _adjacency_faults_fn(mesh, n: int, k_phys: int, euclid: bool,
                              euclid=euclid, max_hops=max_hops)
     f = shard_map(impl, mesh=mesh,
                   in_specs=(P("data"), P(), P()) + (P(),) * 17,
-                  out_specs=(P("data"),) * 4, check_vma=False)
+                  out_specs=(P("data"),) * 5, check_vma=False)
     return jax.jit(f, donate_argnums=(0,) if donate else ())
 
 
@@ -718,7 +725,7 @@ class AdjacencyPipeline:
                     axis=0)
             bits = jax.device_put(jnp.asarray(padded % 2, jnp.int32),
                                   NamedSharding(self.mesh, P("data")))
-            lat, thr, len_sum = self._eval(
+            lat, thr, len_sum, hops = self._eval(
                 bits, self._pair_u, self._pair_v, self._pair_id,
                 self._chain_slot, self._chain_eslot, self._inv_j,
                 self._inv_c, self._col, self._row, self._side, self._phyx,
@@ -733,11 +740,13 @@ class AdjacencyPipeline:
                 with _span("genomes.reports"):
                     reports = self._report_arrays(genomes, deg,
                                                   np.asarray(len_sum)[:Pn])
-                    return GenomeEvalResult(latency=np.asarray(lat)[:Pn],
-                                            throughput=np.asarray(thr)[:Pn],
-                                            reports=reports)
+                    res = GenomeEvalResult(latency=np.asarray(lat)[:Pn],
+                                           throughput=np.asarray(thr)[:Pn],
+                                           reports=reports)
+                self._count_hops(hops)
+                return res
 
-        pending = PendingGenomeEval(finish, (lat, thr, len_sum))
+        pending = PendingGenomeEval(finish, (lat, thr, len_sum, hops))
         return pending
 
     def evaluate(self, genomes: np.ndarray) -> GenomeEvalResult:
@@ -789,8 +798,8 @@ class AdjacencyPipeline:
             fn = _adjacency_faults_fn(self.mesh, self.n, self.k_phys,
                                       self._euclid, self.max_hops,
                                       _donate_ok())
-            lat, thr, reach, len_sum = fn(bits, link_alive, node_alive,
-                                          *self._tables())
+            lat, thr, reach, len_sum, hops = fn(bits, link_alive,
+                                                node_alive, *self._tables())
 
         def finish() -> FaultGridResult:
             with _span("genomes.finish_faults", space="adjacency", pop=Pn):
@@ -806,9 +815,10 @@ class AdjacencyPipeline:
                 _metrics.counter("faults.rows").inc(Pn * F)
                 _metrics.counter("faults.disconnected_rows").inc(int(
                     (res.reachable_fraction < 1.0 - REACH_EPS).sum()))
+                self._count_hops(hops)
                 return res
 
-        pending = PendingGenomeEval(finish, (lat, thr, reach, len_sum))
+        pending = PendingGenomeEval(finish, (lat, thr, reach, len_sum, hops))
         return pending
 
     def evaluate_faults(self, genomes: np.ndarray, link_fail: np.ndarray,
@@ -842,6 +852,16 @@ class AdjacencyPipeline:
                 "output_bytes": int(mem.output_size_in_bytes),
                 "temp_bytes": int(mem.temp_size_in_bytes),
                 "code_bytes": int(mem.generated_code_size_in_bytes)}
+
+    def _count_hops(self, hops) -> None:
+        """The load propagation's hops over every row the program ran
+        (bucket padding included; a fault grid's P·F rows), against its
+        bound ``max_hops``."""
+        hops = np.asarray(hops)
+        _metrics.counter("kernels.load_propagate.hops",
+                         max_hops=self.max_hops).inc(int(hops.sum()))
+        _metrics.counter("kernels.load_propagate.designs",
+                         max_hops=self.max_hops).inc(hops.size)
 
     def _tables(self) -> tuple:
         """The replicated geometry, PHY, bandwidth, traffic and constant

@@ -18,11 +18,15 @@ live in VMEM/registers, the one-hot comparisons are regenerated from iota
 on the fly (never materialized), and the final flow contraction happens in
 the same kernel — zero intermediate HBM traffic.
 
-The kernel runs the shape-stable safety bound ``max_hops`` of fixed
-iterations (converged designs propagate zeros — exact no-ops); the XLA
-fallback instead supports an adaptive while_loop that stops at the batch's
-actual routed diameter, which is the right trade where each hop is a
-separate HBM round-trip anyway.
+Routing is shortest-path in hops, so a design's load has drained after D
+hops, D its routed diameter, and every later hop would propagate exact
+zeros. With ``adaptive`` every backend stops there: the kernels per grid
+step (per design; per design and destination tile when tiled) once the
+load pane is empty, the XLA loops once the batch's (or slab's) load is.
+``max_hops`` stays the safety bound: a table whose unreachable pairs
+self-loop never drains and runs all of it. The result is bit-identical
+to the fixed ``max_hops`` loop either way, and every backend also returns
+the hops it ran for each design (the batch or slab maximum for XLA).
 
 Backend selection mirrors ``kernels.apsp``: compiled Pallas on TPU, the
 pure-XLA loop on CPU/GPU (where the Pallas interpreter would run the kernel
@@ -89,13 +93,13 @@ def default_backend() -> str:
 
 def hop_loop(step, carry, max_hops: int, adaptive: bool, active):
     """The one fixed-length/adaptive hop-iteration scaffold every
-    propagation loop in the package uses.
+    propagation loop in the package uses, the Pallas kernels' included.
 
     ``step``: carry -> carry (one hop). ``active``: carry -> bool scalar;
     with ``adaptive`` the loop stops as soon as it goes False (``max_hops``
     stays the safety bound), otherwise it runs exactly ``max_hops`` steps
     (same result when extra steps are no-ops — e.g. converged loads
-    propagate zeros)."""
+    propagate zeros). Returns (hops run as an int32 scalar, final carry)."""
     if adaptive:
         def cond(state):
             i, c = state
@@ -105,23 +109,24 @@ def hop_loop(step, carry, max_hops: int, adaptive: bool, active):
             i, c = state
             return i + 1, step(c)
 
-        return jax.lax.while_loop(cond, body, (jnp.int32(0), carry))[1]
+        return jax.lax.while_loop(cond, body, (jnp.int32(0), carry))
 
-    def body(c, _):
-        return step(c), None
-
-    return jax.lax.scan(body, carry, None, length=max_hops)[0]
+    # a fori_loop (not a bare scan): Mosaic lowers only the indexed form
+    return (jnp.int32(max_hops),
+            jax.lax.fori_loop(0, max_hops, lambda _, c: step(c), carry))
 
 
 def load_prop_xla(next_hop: jax.Array, load0: jax.Array, max_hops: int,
-                  adaptive: bool) -> tuple[jax.Array, jax.Array]:
+                  adaptive: bool
+                  ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Pure-XLA batched load propagation: the CPU/GPU fallback behind
     ``ops.load_propagate``.
 
     next_hop: [B, n, n] int (src-major: next_hop[u, d]); load0: [B, n, n]
-    f32 dest-major (load0[d, u], diagonal zero). Returns (W, flow): the
-    accumulated dest-major load W[d, u] = Σ_j L_j[d, u] and the directed
-    edge flows flow[u, v] = Σ_d [next_hop[u, d] = v] · W[d, u].
+    f32 dest-major (load0[d, u], diagonal zero). Returns (W, flow, hops):
+    the accumulated dest-major load W[d, u] = Σ_j L_j[d, u], the directed
+    edge flows flow[u, v] = Σ_d [next_hop[u, d] = v] · W[d, u], and the
+    loop's trip count for every design [B] (one loop runs the batch).
 
     The one-hot oh[d, u, v] = [next_hop[u, d] = v] is built ONCE (the table
     is static across hops); each hop is one batched contraction, with
@@ -145,16 +150,36 @@ def load_prop_xla(next_hop: jax.Array, load0: jax.Array, max_hops: int,
     def still_active(state):
         return jnp.any(state[0] > 0)
 
-    _, total = hop_loop(step, (load0, jnp.zeros_like(load0)), max_hops,
-                        adaptive, still_active)
+    hops, (_, total) = hop_loop(step, (load0, jnp.zeros_like(load0)),
+                                max_hops, adaptive, still_active)
     flow = jnp.einsum("bduv,bdu->buv", oh, total, precision=_EXACT)
-    return total, flow
+    return total, flow, jnp.full((B,), hops, jnp.int32)
 
 
-def _load_prop_kernel(max_hops: int, nh_ref, l0_ref, w_ref, f_ref, ld_ref):
+def _kernel_hops(ld_ref, propagate, offdiag, max_hops: int, adaptive: bool):
+    """Both kernels' hop loop over the load pane ``ld_ref`` (the grid
+    step's whole state): each hop adds the standing load into W^T and
+    propagates it. Adaptive, it stops once the pane is empty (loads are
+    non-negative, so its max is 0). Returns (hops run, W^T)."""
+    def step(state):
+        total, _ = state
+        total = total + ld_ref[...]
+        new = jnp.where(offdiag, propagate(), 0.0)
+        ld_ref[...] = new
+        return total, jnp.max(new) > 0
+
+    init = (jnp.zeros(ld_ref.shape, jnp.float32), jnp.max(ld_ref[...]) > 0)
+    hops, (total, _) = hop_loop(step, init, max_hops, adaptive,
+                                lambda state: state[1])
+    return hops, total
+
+
+def _load_prop_kernel(max_hops: int, adaptive: bool, nh_ref, l0_ref, w_ref,
+                      f_ref, h_ref, ld_ref):
     """One design per grid step: the whole propagation plus the flow
     contraction, with every one-hot regenerated from iota comparisons
-    inside VMEM (the [n, n, n] tensor never exists).
+    inside VMEM (the [n, n, n] tensor never exists). ``h_ref`` receives
+    the hops the step ran, across its lanes.
 
     State is src-major — ld_ref[u, d] is the load at u destined for d —
     so the per-source loop reads row u of the next-hop table and of the
@@ -178,13 +203,9 @@ def _load_prop_kernel(max_hops: int, nh_ref, l0_ref, w_ref, f_ref, ld_ref):
         return jax.lax.fori_loop(0, n, body,
                                  jnp.zeros((n, n), jnp.float32))
 
-    def hop(_, total):
-        total = total + ld_ref[...]
-        ld_ref[...] = jnp.where(offdiag, propagate(), 0.0)
-        return total
-
-    w_ref[...] = jax.lax.fori_loop(0, max_hops, hop,
-                                   jnp.zeros((n, n), jnp.float32))
+    hops, w = _kernel_hops(ld_ref, propagate, offdiag, max_hops, adaptive)
+    w_ref[...] = w
+    h_ref[...] = jnp.full(h_ref.shape, hops, jnp.int32)
 
     # flow^T[v, u] = Σ_d [nh[u, d] = v] · W^T[u, d]: column u of flow^T is
     # a lane reduction, placed by a lane-iota select.
@@ -199,28 +220,37 @@ def _load_prop_kernel(max_hops: int, nh_ref, l0_ref, w_ref, f_ref, ld_ref):
                                    jnp.zeros((n, n), jnp.float32))
 
 
-@functools.partial(jax.jit, static_argnames=("max_hops", "interpret"))
+# Each grid step writes its hop count across one row of 128 lanes: a
+# (1, 128) block spans the output's last two dims whole, as Mosaic needs.
+_HOPS_LANES = 128
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("max_hops", "adaptive", "interpret"))
 def load_prop_pallas(next_hop: jax.Array, load0: jax.Array, max_hops: int,
-                     *, interpret: bool = True
-                     ) -> tuple[jax.Array, jax.Array]:
+                     adaptive: bool = True, *, interpret: bool = True
+                     ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Batched fused load propagation. next_hop: [B, n, n] int32 src-major
     (padding rows/cols must be self-loops); load0: [B, n, n] f32 dest-major
-    with zero padding. Returns (W dest-major, directed flow)."""
+    with zero padding. Returns (W dest-major, directed flow, hops [B])."""
     B, n, _ = next_hop.shape
-    kernel = functools.partial(_load_prop_kernel, max_hops)
+    kernel = functools.partial(_load_prop_kernel, max_hops, adaptive)
     block = pl.BlockSpec((None, n, n), lambda b: (b, 0, 0))
-    w_t, f_t = pl.pallas_call(
+    w_t, f_t, hops = pl.pallas_call(
         kernel,
         grid=(B,),
         in_specs=[block, block],
-        out_specs=[block, block],
+        out_specs=[block, block,
+                   pl.BlockSpec((None, 1, _HOPS_LANES),
+                                lambda b: (b, 0, 0))],
         out_shape=[jax.ShapeDtypeStruct((B, n, n), jnp.float32),
-                   jax.ShapeDtypeStruct((B, n, n), jnp.float32)],
+                   jax.ShapeDtypeStruct((B, n, n), jnp.float32),
+                   jax.ShapeDtypeStruct((B, 1, _HOPS_LANES), jnp.int32)],
         scratch_shapes=[pltpu.VMEM((n, n), jnp.float32)],
         interpret=interpret,
     )(next_hop.astype(jnp.int32),
       load0.astype(jnp.float32).swapaxes(-1, -2))
-    return w_t.swapaxes(-1, -2), f_t.swapaxes(-1, -2)
+    return w_t.swapaxes(-1, -2), f_t.swapaxes(-1, -2), hops[:, 0, 0]
 
 
 # --------------------------------------------------------------------------
@@ -243,14 +273,15 @@ def pick_tile(n: int, batch: int, budget_elems: int = 1 << 25) -> int:
 
 def load_prop_xla_blocked(next_hop: jax.Array, load0: jax.Array,
                           max_hops: int, adaptive: bool, tile: int
-                          ) -> tuple[jax.Array, jax.Array]:
+                          ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Destination-blocked XLA load propagation: bit-compatible with
     ``load_prop_xla`` but scans over ``tile``-row destination slabs so the
     transient one-hot is [B, tile, n, n] instead of [B, n, n, n].
 
     Each slab runs its own hop loop (adaptive slabs stop at the slab's own
     routed eccentricity — strictly earlier than the batch diameter); the
-    flow matrix is the scan carry, accumulated across slabs. Tile sizes
+    flow matrix is the scan carry, accumulated across slabs, and every
+    design's hops are the largest slab's trip count. Tile sizes
     that don't divide n are handled by zero-padding the destination axis:
     padded rows carry zero load and contribute nothing.
     """
@@ -282,25 +313,27 @@ def load_prop_xla_blocked(next_hop: jax.Array, load0: jax.Array,
         def still_active(state):
             return jnp.any(state[0] > 0)
 
-        _, total = hop_loop(step, (load0s, jnp.zeros_like(load0s)),
-                            max_hops, adaptive, still_active)
+        hops, (_, total) = hop_loop(step, (load0s, jnp.zeros_like(load0s)),
+                                    max_hops, adaptive, still_active)
         return flow + jnp.einsum("btuv,btu->buv", oh, total,
-                                 precision=_EXACT), total
+                                 precision=_EXACT), (total, hops)
 
     flow0 = jnp.zeros((B, n, n), jnp.float32)
-    flow, w_t = jax.lax.scan(
+    flow, (w_t, hops) = jax.lax.scan(
         slab, flow0, (nh_t.swapaxes(0, 1), l0_t.swapaxes(0, 1), d_t))
     w = w_t.swapaxes(0, 1).reshape(B, n_pad, n)[:, :n]
-    return w, flow
+    return w, flow, jnp.full((B,), jnp.max(hops), jnp.int32)
 
 
-def _load_prop_tiled_kernel(max_hops: int, nh_ref, l0_ref, w_ref, f_ref,
-                            ld_ref):
+def _load_prop_tiled_kernel(max_hops: int, adaptive: bool, nh_ref, l0_ref,
+                            w_ref, f_ref, h_ref, ld_ref):
     """One (design, destination-tile) pair per grid step, in the fused
     kernel's src-major layout: the destination tile is a [n, tile] lane
     slab of the next-hop table and load, and the shared flow^T pane [n, n]
     is revisited across the inner (tile) grid axis and accumulated in
-    place. Compiled, ``tile`` must be a multiple of the 128-lane width."""
+    place. The tile's hop loop stops at its own destinations' largest
+    eccentricity; ``h_ref`` receives its hops. Compiled, ``tile`` must be
+    a multiple of the 128-lane width."""
     t = pl.program_id(1)
     n, tile = l0_ref.shape
     viota = jax.lax.broadcasted_iota(jnp.int32, (n, tile), 0)
@@ -317,13 +350,9 @@ def _load_prop_tiled_kernel(max_hops: int, nh_ref, l0_ref, w_ref, f_ref,
         return jax.lax.fori_loop(0, n, body,
                                  jnp.zeros((n, tile), jnp.float32))
 
-    def hop(_, total):
-        total = total + ld_ref[...]
-        ld_ref[...] = jnp.where(offdiag, propagate(), 0.0)
-        return total
-
-    w_ref[...] = jax.lax.fori_loop(0, max_hops, hop,
-                                   jnp.zeros((n, tile), jnp.float32))
+    hops, w = _kernel_hops(ld_ref, propagate, offdiag, max_hops, adaptive)
+    w_ref[...] = w
+    h_ref[...] = jnp.full(h_ref.shape, hops, jnp.int32)
 
     @pl.when(t == 0)
     def _init():
@@ -343,33 +372,37 @@ def _load_prop_tiled_kernel(max_hops: int, nh_ref, l0_ref, w_ref, f_ref,
         0, n, f_body, jnp.zeros((n, n), jnp.float32))
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("max_hops", "tile", "interpret"))
+@functools.partial(jax.jit, static_argnames=("max_hops", "tile",
+                                             "adaptive", "interpret"))
 def load_prop_pallas_tiled(next_hop: jax.Array, load0: jax.Array,
-                           max_hops: int, tile: int, *,
-                           interpret: bool = True
-                           ) -> tuple[jax.Array, jax.Array]:
+                           max_hops: int, tile: int, adaptive: bool = True,
+                           *, interpret: bool = True
+                           ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Destination-tiled fused load propagation: grid (batch × dest-tile)
     streaming [n, tile] src-major slabs through VMEM. Same contract as
     ``load_prop_pallas`` (self-loop padding rows, zero-padded load); the
     destination axis must additionally be a multiple of ``tile``, which
     ``ops.load_propagate`` guarantees (128-lane tiles of the 128-lane
-    padding)."""
+    padding). A design's hops are the largest of its tiles'."""
     B, n, _ = next_hop.shape
     if n % tile:
         raise ValueError(f"tile {tile} must divide padded n {n}")
     nt = n // tile
-    kernel = functools.partial(_load_prop_tiled_kernel, max_hops)
+    kernel = functools.partial(_load_prop_tiled_kernel, max_hops, adaptive)
     slab = pl.BlockSpec((None, n, tile), lambda b, t: (b, 0, t))
-    w_t, f_t = pl.pallas_call(
+    w_t, f_t, hops = pl.pallas_call(
         kernel,
         grid=(B, nt),
         in_specs=[slab, slab],
-        out_specs=[slab, pl.BlockSpec((None, n, n), lambda b, t: (b, 0, 0))],
+        out_specs=[slab, pl.BlockSpec((None, n, n), lambda b, t: (b, 0, 0)),
+                   pl.BlockSpec((None, None, 1, _HOPS_LANES),
+                                lambda b, t: (b, t, 0, 0))],
         out_shape=[jax.ShapeDtypeStruct((B, n, n), jnp.float32),
-                   jax.ShapeDtypeStruct((B, n, n), jnp.float32)],
+                   jax.ShapeDtypeStruct((B, n, n), jnp.float32),
+                   jax.ShapeDtypeStruct((B, nt, 1, _HOPS_LANES), jnp.int32)],
         scratch_shapes=[pltpu.VMEM((n, tile), jnp.float32)],
         interpret=interpret,
     )(next_hop.astype(jnp.int32),
       load0.astype(jnp.float32).swapaxes(-1, -2))
-    return w_t.swapaxes(-1, -2), f_t.swapaxes(-1, -2)
+    return (w_t.swapaxes(-1, -2), f_t.swapaxes(-1, -2),
+            jnp.max(hops[:, :, 0, 0], axis=1))

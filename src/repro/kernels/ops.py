@@ -135,6 +135,17 @@ def load_propagate(next_hop: jax.Array, load0: jax.Array,
                    max_hops: int | None = None, adaptive: bool = True,
                    backend: str | None = None
                    ) -> tuple[jax.Array, jax.Array]:
+    """(W, flow) of ``load_propagate_counted``, without the hop counts."""
+    w, flow, _ = load_propagate_counted(next_hop, load0, max_hops, adaptive,
+                                        backend)
+    return w, flow
+
+
+def load_propagate_counted(next_hop: jax.Array, load0: jax.Array,
+                           max_hops: int | None = None,
+                           adaptive: bool = True,
+                           backend: str | None = None
+                           ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Accumulated per-destination load + directed edge flows behind one
     backend-aware entry (the shared primitive of ``edge_flows``,
     ``edge_flows_load`` and the fused genome pipeline's proxies).
@@ -145,10 +156,13 @@ def load_propagate(next_hop: jax.Array, load0: jax.Array,
     for d; the diagonal is masked off defensively). Returns
 
         W[d, u]    = Σ_j L_j[d, u]  (per-hop loads summed — every unit of
-                     traffic counted once per hop departure from u), and
+                     traffic counted once per hop departure from u),
         flow[u, v] = Σ_d [next_hop[u, d] = v] · W[d, u]  (directed edge
                      flows; traffic-weighted latency is Σ W · step_cost of
-                     the chosen hop, see ``dse.genomes._eval_proxies``).
+                     the chosen hop, see ``dse.genomes._eval_proxies``),
+                     and
+        hops[b]    = the hops the loop ran for design b, int32 (a scalar
+                     for an [n, n] table).
 
     ``backend`` is one of ``load_prop.LOAD_PROP_BACKENDS``; ``None``
     auto-selects via ``load_prop.default_backend()`` — the fused Pallas
@@ -160,16 +174,20 @@ def load_propagate(next_hop: jax.Array, load0: jax.Array,
     ``REPRO_LOAD_PROP_TILE`` pins the tile size (else ``LANE_TILE`` for
     the tiled kernel and ``load_prop.pick_tile`` for ``xla_blocked``;
     compiled, a pinned Pallas tile must be a multiple of 128).
-    ``adaptive`` (XLA backends only) swaps the
-    fixed-length scan for a while_loop that stops at the batch's routed
-    diameter — per destination slab in the blocked variant; the fused
-    kernels always run the shape-stable ``max_hops`` bound (extra steps
-    propagate zeros — exact no-ops). The env-driven default is resolved
-    outside this function's own jit boundary, so direct callers pick up a
-    flipped ``REPRO_LOAD_PROP_BACKEND`` on their next call — but *jitted*
-    callers (``edge_flows``, the genome pipelines) resolve it at their
-    trace time and keep the backend baked into their compiled programs;
-    set the variable before first use.
+    ``adaptive`` swaps the fixed ``max_hops`` loop (default n − 1) for one
+    that stops once the load has drained, at the routed diameter: per
+    design in the fused kernel, per design and destination tile in the
+    tiled one (a design's hops are its largest tile's), per batch in
+    ``xla`` and per destination slab in ``xla_blocked`` (hops: the batch's
+    or the largest slab's trip count). Drained hops propagate exact zeros,
+    so W and flow are bit-identical to the fixed loop; a design with a
+    self-looped unreachable pair never drains and runs all ``max_hops``.
+    The env-driven default is resolved outside this function's own jit
+    boundary, so direct callers pick up a flipped
+    ``REPRO_LOAD_PROP_BACKEND`` on their next call — but *jitted* callers
+    (``edge_flows``, the genome pipelines) resolve it at their trace time
+    and keep the backend baked into their compiled programs; set the
+    variable before first use.
     """
     from .load_prop import default_backend
     from ..faults.harness import maybe_chaos_fail, run_with_fallback
@@ -204,7 +222,7 @@ def load_propagate(next_hop: jax.Array, load0: jax.Array,
 def _load_propagate(next_hop: jax.Array, load0: jax.Array,
                     max_hops: int | None, adaptive: bool, backend: str,
                     tile: int | None = None
-                    ) -> tuple[jax.Array, jax.Array]:
+                    ) -> tuple[jax.Array, jax.Array, jax.Array]:
     from .load_prop import (load_prop_pallas, load_prop_pallas_tiled,
                             load_prop_xla, load_prop_xla_blocked)
 
@@ -219,12 +237,12 @@ def _load_propagate(next_hop: jax.Array, load0: jax.Array,
     if max_hops is None:
         max_hops = max(n - 1, 1)
     if backend == "xla":
-        w, flow = load_prop_xla(next_hop, load0.astype(jnp.float32),
-                                max_hops, adaptive)
+        w, flow, hops = load_prop_xla(next_hop, load0.astype(jnp.float32),
+                                      max_hops, adaptive)
     elif backend == "xla_blocked":
-        w, flow = load_prop_xla_blocked(next_hop,
-                                        load0.astype(jnp.float32),
-                                        max_hops, adaptive, tile)
+        w, flow, hops = load_prop_xla_blocked(next_hop,
+                                              load0.astype(jnp.float32),
+                                              max_hops, adaptive, tile)
     else:
         n_lane = _round_up(n, 128)
         nh_p = jnp.tile(jnp.arange(n_lane, dtype=jnp.int32)[:, None],
@@ -233,17 +251,17 @@ def _load_propagate(next_hop: jax.Array, load0: jax.Array,
         l0_p = _set_block(jnp.zeros((B, n_lane, n_lane), jnp.float32),
                           load0)
         if backend in ("pallas_tiled", "pallas_tiled_interpret"):
-            w, flow = load_prop_pallas_tiled(
-                nh_p, l0_p, max_hops, tile,
+            w, flow, hops = load_prop_pallas_tiled(
+                nh_p, l0_p, max_hops, tile, adaptive,
                 interpret=backend == "pallas_tiled_interpret")
         else:
-            w, flow = load_prop_pallas(
-                nh_p, l0_p, max_hops,
+            w, flow, hops = load_prop_pallas(
+                nh_p, l0_p, max_hops, adaptive,
                 interpret=backend == "pallas_interpret")
         w, flow = w[:, :n, :n], flow[:, :n, :n]
     if squeeze:
-        return w[0], flow[0]
-    return w, flow
+        return w[0], flow[0], hops[0]
+    return w, flow, hops
 
 
 def apsp(d: jax.Array, n_iters: int | None = None,
@@ -342,5 +360,5 @@ def _apsp(d: jax.Array, n_iters: int | None, backend: str,
 
 
 __all__ = ["minplus_matmul", "flow_accumulate", "apsp", "load_propagate",
-           "load_prop_tile",
+           "load_propagate_counted", "load_prop_tile",
            "minplus_ref", "flow_accumulate_ref", "BIG"]

@@ -7,7 +7,8 @@
 * ``telemetry`` — the derived health numbers the benchmarks and CI gates
   consume: async overlap %, structure-cache hit rate, jit compile counts,
   per-backend kernel dispatch counts, completed evals/s, p99 step
-  latency and the fault grid's row counts. This is the ``telemetry``
+  latency, the fault grid's row counts and the load propagation's mean
+  hops per design against its bound. This is the ``telemetry``
   block committed into BENCH_opt.json.
 
 ``format_report`` renders the human table; ``dump_run`` exports everything
@@ -166,6 +167,19 @@ def telemetry(snapshot: dict) -> dict:
                                                 "faults.infeasible"))}
               if fault_rows else None)
 
+    # -- load propagation: hops run per design, against the bound n - 1
+    # that labels each pipeline's counters
+    hops = {c["labels"]["max_hops"]: c["value"]
+            for c in _counters(snapshot, "kernels.load_propagate.hops")}
+    hop_rows = [{"max_hops": c["labels"]["max_hops"],
+                 "designs": int(c["value"]),
+                 "hops": int(hops[c["labels"]["max_hops"]]),
+                 "mean_hops": round(hops[c["labels"]["max_hops"]]
+                                    / c["value"], 3)}
+                for c in _counters(snapshot, "kernels.load_propagate.designs")
+                if c["value"]]
+    hop_rows.sort(key=lambda r: r["max_hops"])
+
     return {
         "async_overlap_pct": (round(overlap, 2)
                               if overlap is not None else None),
@@ -186,6 +200,7 @@ def telemetry(snapshot: dict) -> dict:
                          "total_s": round(ingest_s["sum"], 4)}
                         if ingest_s else None),
         "faults": faults,
+        "load_propagate_hops": hop_rows or None,
     }
 
 
@@ -254,6 +269,9 @@ def format_report(summary: dict) -> str:
         lines.append(f"fault grid:           {f['rows']} scenario rows, "
                      f"{f['disconnected_rows']} disconnected traffic; "
                      f"{f['infeasible']} designs infeasible")
+    for r in t["load_propagate_hops"] or ():
+        lines.append(f"load-prop hops:       {r['mean_hops']:.4g} a design "
+                     f"of at most {r['max_hops']} ({r['designs']} designs)")
     lines += ["", "-- spans --"]
     header = ("span", "count", "total_s", "p50_s", "p99_s", "threads")
     rows = [header]

@@ -413,6 +413,10 @@ def _synthetic_snapshot():
             {"name": "faults.rows", "labels": {}, "value": 330},
             {"name": "faults.disconnected_rows", "labels": {}, "value": 12},
             {"name": "faults.infeasible", "labels": {}, "value": 7},
+            {"name": "kernels.load_propagate.hops",
+             "labels": {"max_hops": 63}, "value": 1650},
+            {"name": "kernels.load_propagate.designs",
+             "labels": {"max_hops": 63}, "value": 330},
         ],
         "gauges": [],
         "histograms": [
@@ -437,6 +441,8 @@ def test_telemetry_derivation():
     assert t["evals_per_s"] == 240.0
     assert t["faults"] == {"rows": 330, "disconnected_rows": 12,
                            "infeasible": 7}
+    assert t["load_propagate_hops"] == [
+        {"max_hops": 63, "designs": 330, "hops": 1650, "mean_hops": 5.0}]
 
 
 def test_telemetry_degrades_on_empty_snapshot():
@@ -448,6 +454,7 @@ def test_telemetry_degrades_on_empty_snapshot():
     assert t["jit_compiles"]["total"] == 0
     assert t["kernel_dispatch"] == {}
     assert t["faults"] is None
+    assert t["load_propagate_hops"] is None
 
 
 def test_summarize_and_format_report():
@@ -466,6 +473,8 @@ def test_summarize_and_format_report():
     assert "async overlap:" in text and "75.0%" in text
     assert "evals/s:              240 completed" in text
     assert "fault grid:           330 scenario rows, 12 disconnected" in text
+    assert ("load-prop hops:       5 a design of at most 63 (330 designs)"
+            in text)
     assert "opt.generation" in text
 
 

@@ -51,17 +51,22 @@ def _compile(jitted, *args, **static):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("adaptive", [True, False],
+                         ids=["adaptive", "fixed"])
 @pytest.mark.parametrize("n,batch,backend", [
     (64, 256, "pallas"),           # fused kernel: n <= 160
     (256, 32, "pallas_tiled"),     # tiled kernel above
     (576, 16, "pallas_tiled"),
 ])
-def test_load_propagate_kernel_compiles(one_chip, n, batch, backend):
+def test_load_propagate_kernel_compiles(one_chip, n, batch, backend,
+                                        adaptive):
+    """Both hop-loop forms reach Mosaic: the loop that stops once the load
+    pane drains, and the fixed n - 1 hops."""
     from repro.kernels.ops import _load_propagate, load_prop_tile
 
     nh = _spec((batch, n, n), jnp.int16, one_chip)
     l0 = _spec((batch, n, n), jnp.float32, one_chip)
-    _compile(_load_propagate, nh, l0, max_hops=n - 1, adaptive=True,
+    _compile(_load_propagate, nh, l0, max_hops=n - 1, adaptive=adaptive,
              backend=backend, tile=load_prop_tile(backend, n, batch))
 
 
